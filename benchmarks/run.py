@@ -10,6 +10,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
     from . import (fig2_mixed_precision, roofline_table, table1_granularity,
                    table2_weight_only, table3_full_quant, table4_cost,
                    table5_calib_speed, table6_deploy)
@@ -24,6 +26,7 @@ def main() -> None:
         ("table6_deploy", table6_deploy.main),
         ("fig2_mixed_precision", fig2_mixed_precision.main),
     ]
+    use_compile_cache()
     only = sys.argv[1:] if len(sys.argv) > 1 else None
     for name, fn in tables:
         if only and name not in only:

@@ -71,7 +71,7 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
     """Audit every launch the qmm dispatch would issue for one packed
     leaf (decode + prefill tiers; grouped for stacked experts)."""
     from ...kernels.spec import (describe_qgemv, describe_qmatmul,
-                                 describe_qmatmul_grouped)
+                                 describe_qmatmul_grouped, qmm_row_tile)
 
     # strip the scan-stack dim: the runtime slices one layer per step
     if len(wp_shape) >= 3 and len(qs_shape) == len(wp_shape):
@@ -88,13 +88,18 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
     wp2 = (rows, npad)
     qs2 = (qs_shape[-2], npad)
     subject = f"{arch}:{path}"
+    try:
+        bm = qmm_row_tile(PREFILL_M, K, qs2[0], bits=bits, bn=bn)
+    except KernelSpecError as e:
+        emit("kernel_tile_divisibility", subject, str(e))
+        return
     launches = []
     if len(wp_shape) == 2:
         launches = [
             ("decode", lambda: describe_qgemv(
                 (DECODE_M, K), wp2, qs2, bits=bits, bn=bn)),
             ("prefill", lambda: describe_qmatmul(
-                (PREFILL_M, K), wp2, qs2, bits=bits, bm=128, bn=bn)),
+                (PREFILL_M, K), wp2, qs2, bits=bits, bm=bm, bn=bn)),
         ]
     elif len(wp_shape) == 3:
         E = wp_shape[0]
@@ -103,7 +108,7 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
             ("grouped-decode", lambda: describe_qmatmul_grouped(
                 (E, DECODE_M, K), wp3, qs3, bits=bits, bm=DECODE_M, bn=bn)),
             ("grouped-prefill", lambda: describe_qmatmul_grouped(
-                (E, PREFILL_M, K), wp3, qs3, bits=bits, bm=128, bn=bn)),
+                (E, PREFILL_M, K), wp3, qs3, bits=bits, bm=bm, bn=bn)),
         ]
     for tier, describe in launches:
         try:
@@ -118,10 +123,10 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
 
 
 def _sweep_kv(arch: str, cfg, emit: Callable[[str, str, str], None]) -> None:
-    from ...kernels.spec import describe_kv_decode
+    from ...kernels.spec import describe_kv_decode, kv_stream_tile
 
     S = KV_SEQ
-    bs = 512 if S % 512 == 0 else (128 if S % 128 == 0 else S)
+    bs = kv_stream_tile(S)
     q_shape = (DECODE_M, cfg.n_heads, cfg.hd)
     k8_shape = (DECODE_M, S, cfg.n_kv_heads, cfg.hd)
     try:
@@ -137,10 +142,9 @@ def _sweep_kv(arch: str, cfg, emit: Callable[[str, str, str], None]) -> None:
 
 def _sweep_fakequant(arch: str, path: str, K: int, N: int,
                      emit: Callable[[str, str, str], None]) -> None:
-    from ...kernels.spec import describe_fakequant, largest_tile
+    from ...kernels.spec import describe_fakequant, fakequant_tiles
 
-    bk = largest_tile(K, 256)
-    bn = largest_tile(N, 256)
+    bk, bn = fakequant_tiles(K, N)
     try:
         sp = describe_fakequant((K, N), (1, N), bk=bk, bn=bn)
     except KernelSpecError as e:

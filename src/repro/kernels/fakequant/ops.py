@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 
 from ...core.quantizer import QConfig, QState
-from ..spec import KernelSpecError, largest_tile
+from ..spec import KernelSpecError, fakequant_tiles
 from .kernel import fakequant
 from .ref import fakequant_ref
 
@@ -49,8 +49,6 @@ def adaround_forward(w: jax.Array, v: jax.Array, st: QState, cfg: QConfig,
     if backend == "xla":
         return fakequant_ref(w, v, scale, cfg.qmin, cfg.qmax, hard)
     interpret = jax.default_backend() != "tpu"
-    K, N = w.shape
-    bk = largest_tile(K, 256)
-    bn = largest_tile(N, 256)
+    bk, bn = fakequant_tiles(*w.shape)
     return fakequant(w, v, scale, qmin=cfg.qmin, qmax=cfg.qmax, hard=hard,
                      bk=bk, bn=bn, interpret=interpret)

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..spec import kv_stream_tile
 from .kernel import kv_decode
 from .ref import kv_decode_ref
 
@@ -60,7 +61,6 @@ def attend_int8(q: Array, k8: Array, v8: Array, kscale: Array, vscale: Array,
     if backend == "xla":
         return kv_decode_ref(q, k8, v8, kscale, vscale, kpos, cur_pos, window)
     interpret = jax.default_backend() != "tpu"
-    S = k8.shape[1]
-    bs = 512 if S % 512 == 0 else (128 if S % 128 == 0 else S)
     return kv_decode(q, k8, v8, kscale, vscale, kpos, cur_pos,
-                     window=window, bs=bs, interpret=interpret)
+                     window=window, bs=kv_stream_tile(k8.shape[1]),
+                     interpret=interpret)

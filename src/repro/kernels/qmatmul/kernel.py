@@ -2,26 +2,34 @@
 
 The serving GEMMs for BRECQ-quantized models: weights live in HBM as
 packed int2/int4/int8 codes (offset-binary, packed along the reduction
-axis) with per-group scales; the kernels stream (bk, bn) weight tiles
-into VMEM, unpack + dequantize in-register, and accumulate on the MXU
-in f32. Three entry points, one per serving tier (see ``ops.qmm``):
+axis) with per-group scales; the kernels stream (bkp, bn) packed weight
+tiles into VMEM, unpack + dequantize in-register, and accumulate on the
+MXU in f32. Three entry points, one per serving tier (see ``ops.qmm``):
 
   qmatmul          prefill GEMM — grid over (M, N, K) tiles
   qgemv            decode GEMV — M = batch rows (<= 8), no M grid and no
-                   M padding; scale-major k loop (scales applied to the
-                   (M, bn) partial sum, not the (bk, bn) weight tile)
-  qmatmul_grouped  stacked MoE experts — qgemv's schedule with a leading
-                   expert grid dim consuming (E, K/per, N) nodes directly
+                   M padding
+  qmatmul_grouped  stacked MoE experts — a leading expert grid dim
+                   consuming (E, K/per, N) nodes directly
 
-Tiling (VMEM working set per step, defaults bm=bn=128, bk=group):
-  x tile      (bm, bk)            bf16/f32
-  w tile      (bk/per, bn) int8   <- 8/bits codes per byte
-  scale tile  (1, bn)             one group per k-step (bk == group_size)
+Field planes. Packed row r holds the ``per = 8/bits`` codes of K rows
+r*per .. r*per+per-1, field i in bits [i*bits, (i+1)*bits). Instead of
+interleaving the fields back into K order in-kernel (a sublane relayout
+Mosaic does not lower), the wrapper splits x into ``per`` planes
+``x3[i, m, r] = x[m, r*per + i]`` and the kernel sums one dot per field:
+``x @ W == sum_i x3[i] @ field_i``. Shifts and masks run in int32, the
+integer width Mosaic legalizes for vector arithmetic.
+
+Tiling (VMEM working set per step, bn = 128):
+  x tile      (per, bm, bkp)        f32/bf16; bkp packed rows on lanes
+  w tile      (bkp, bn) int8        <- 8/bits codes per byte
+  scale tile  (ng, 1, bn)           ng = scale groups in the k-step
   acc scratch (bm, bn) f32
 
-Constraint: group_size == bk (one scale row per k-tile), or per-channel
-scales (scales shape (1, N)). MXU dims stay multiples of 128 on the
-prefill tier; the decode tiers keep M at the true row count.
+bkp is a multiple of 128 (the x block's lane dim) or all K/per rows
+(``spec._pick_k_step``). Per-channel scales multiply the (bm, bn)
+partial sum after the dots; grouped scales (a k-step may span several
+groups) dequantize the field tiles, one scale row per group.
 """
 from __future__ import annotations
 
@@ -38,45 +46,79 @@ from ..spec import (describe_qgemv, describe_qmatmul,
 Array = jax.Array
 
 
-def _unpack_tile(wp: Array, bits: int) -> Array:
-    """(bk/per, bn) int8 -> (bk, bn) f32 centred codes.
-
-    All integer work stays in int8: the arithmetic right shift
-    sign-extends, but ``& mask`` keeps only the low ``bits`` bits, which
-    match the logical-shift result whenever shift+bits <= 8 — so no
-    widening to int32 and no unsigned view are needed. One broadcasted
-    shift replaces the per-field temporaries + stack, leaving a single
-    reshape to interleave the ``per`` fields along the k axis.
-    """
+def _unpack_fields(wp: Array, bits: int) -> list[Array]:
+    """(bkp, bn) int8 -> ``per`` (bkp, bn) f32 planes of centred codes;
+    plane i row r is the code of K row ``r*per + i``."""
     if bits == 8:
-        return wp.astype(jnp.float32)
-    per = 8 // bits
-    mask = jnp.int8((1 << bits) - 1)
-    # iota (not a captured constant: Pallas kernels must build arrays
-    # in-kernel) gives the per-field shift amounts 0, bits, 2*bits, ...
-    shifts = (jax.lax.broadcasted_iota(jnp.int32, (1, per, 1), 1)
-              .astype(jnp.int8) * jnp.int8(bits))
-    fields = (wp[:, None, :] >> shifts) & mask  # (bk/per, per, bn)
-    codes = fields.reshape(wp.shape[0] * per, wp.shape[1]) - jnp.int8(2 ** (bits - 1))
-    return codes.astype(jnp.float32)
+        return [wp.astype(jnp.float32)]
+    w = wp.astype(jnp.int32)
+    mask, off = (1 << bits) - 1, 1 << (bits - 1)
+    return [(((w >> (i * bits)) & mask) - off).astype(jnp.float32)
+            for i in range(8 // bits)]
 
 
-def _qmatmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits: int, nk: int):
-    k = pl.program_id(2)
+def field_planes(x: Array, per: int) -> Array:
+    """(..., M, K) -> (..., per, M, K/per): plane i holds K rows i,
+    i + per, i + 2*per, ... — the rows of packed field i."""
+    *lead, m, k = x.shape
+    return jnp.moveaxis(x.reshape(*lead, m, k // per, per), -1, -3)
+
+
+def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits: int, nk: int,
+                grouped: bool, k_axis: int, lead: int):
+    """One output tile's k-step: acc += sum_i x plane i @ dequant(field
+    i). ``lead`` unit block dims (the grouped tier's expert axis) prefix
+    every operand; the last k-step writes the (bm, bn) output tile."""
+    k = pl.program_id(k_axis)
+    at = (0,) * lead
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack_tile(w_ref[...], bits)  # (bk, bn)
-    w = codes * s_ref[...].astype(jnp.float32)  # scale row broadcasts
-    acc_ref[...] += jax.lax.dot(
-        x_ref[...].astype(jnp.float32), w,
-        preferred_element_type=jnp.float32)
+    wp = w_ref[(*at, Ellipsis)]  # (bkp, bn) int8
+    s = s_ref[(*at, Ellipsis)].astype(jnp.float32)  # (ng, 1, bn)
+    fields = _unpack_fields(wp, bits)
+    if grouped:  # one scale row per group of bkp/ng packed rows
+        bkp, bn = wp.shape
+        ng = s.shape[0]
+        srow = jnp.broadcast_to(s, (ng, bkp // ng, bn)).reshape(bkp, bn)
+        fields = [f * srow for f in fields]
+    part = None
+    for i, f in enumerate(fields):
+        d = jax.lax.dot(x_ref[(*at, i, Ellipsis)].astype(jnp.float32), f,
+                        preferred_element_type=jnp.float32)
+        part = d if part is None else part + d
+    if not grouped:  # per-channel: (x @ codes) * s == x @ (codes * s)
+        part = part * s[0]
+    acc_ref[...] += part
 
     @pl.when(k == nk - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[(*at, Ellipsis)] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _launch(x: Array, w_packed: Array, scales: Array, sp, *, bits: int,
+            index_maps, interpret: bool) -> Array:
+    """pallas_call one qmm tier from its spec and (x, w, scales, out)
+    index maps; x goes in as field planes, scales as (..., G, 1, N)."""
+    m, blocks = sp.meta, sp.blocks
+    kernel = functools.partial(
+        _qmm_kernel, bits=bits, nk=m["nk"], grouped=scales.shape[-2] > 1,
+        k_axis=len(sp.grid) - 1, lead=x.ndim - 2)
+    xs, ws, ss, os_ = index_maps
+    return pl.pallas_call(
+        kernel,
+        grid=sp.grid,
+        in_specs=[pl.BlockSpec(blocks["x"][0], xs),
+                  pl.BlockSpec(blocks["w"][0], ws),
+                  pl.BlockSpec(blocks["scales"][0], ss)],
+        out_specs=pl.BlockSpec(blocks["out"][0], os_),
+        out_shape=jax.ShapeDtypeStruct(x.shape[:-1] + w_packed.shape[-1:],
+                                       x.dtype),
+        scratch_shapes=[pltpu.VMEM((m["bm"], m["bn"]), jnp.float32)],
+        interpret=interpret,
+    )(field_planes(x, m["per"]), w_packed, scales[..., None, :])
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "interpret"))
@@ -87,53 +129,18 @@ def qmatmul(x: Array, w_packed: Array, scales: Array, *, bits: int,
     Tile-math violations raise :class:`~repro.kernels.spec.KernelSpecError`
     with the offending shapes named (see ``spec.describe_qmatmul``).
     """
-    M, K = x.shape
+    M, _ = x.shape
     N = w_packed.shape[1]
-    G = scales.shape[0]
-    bm = min(bm, M)
-    bn = min(bn, N)
     sp = describe_qmatmul(x.shape, w_packed.shape, scales.shape, bits=bits,
-                          bm=bm, bn=bn, x_bytes=x.dtype.itemsize)
-    per, bk, nk = sp.meta["per"], sp.meta["bk"], sp.meta["nk"]
-
-    grid = sp.grid
-    return pl.pallas_call(
-        functools.partial(_qmatmul_kernel, bits=bits, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // per, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k if G > 1 else 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w_packed, scales)
-
-
-def _qgemv_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits: int, nk: int):
-    """Decode GEMV step: dot raw codes, then scale the (M, bn) partial.
-
-    The scale row is uniform within the k-step's group, so
-    ``(x @ (codes * s)) == (x @ codes) * s`` exactly — applying it after
-    the dot turns bk*bn dequant multiplies into M*bn (M <= 8), and the
-    f32 dequantized weight tile never exists, in VMEM or HBM.
-    """
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    codes = _unpack_tile(w_ref[...], bits)  # (bk, bn), centred f32 codes
-    part = jax.lax.dot(x_ref[...].astype(jnp.float32), codes,
-                       preferred_element_type=jnp.float32)
-    acc_ref[...] += part * s_ref[...].astype(jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+                          bm=min(bm, M), bn=min(bn, N),
+                          x_bytes=x.dtype.itemsize)
+    sk = scales.shape[0] > 1  # grouped: the scale block walks k
+    return _launch(x, w_packed, scales, sp, bits=bits, interpret=interpret,
+                   index_maps=(
+                       lambda i, j, k: (0, i, k),
+                       lambda i, j, k: (k, j),
+                       lambda i, j, k: (k if sk else 0, 0, j),
+                       lambda i, j, k: (i, j)))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bn", "interpret"))
@@ -143,51 +150,20 @@ def qgemv(x: Array, w_packed: Array, scales: Array, *, bits: int,
 
     M is the decode batch (a handful of rows): the whole M extent is one
     skinny block — no M grid dim and no zero-row padding to the 8/128
-    sublane tile. The grid is (N tiles, k steps) with k innermost
-    ("scale-major": the k-loop walks scale groups while the (M, bn)
-    accumulator stays resident in VMEM), and each step applies its scale
-    row to the partial sum instead of the weight tile.
+    sublane tile. The grid is (N tiles, k steps) with k innermost: the
+    (M, bn) accumulator stays resident in VMEM while the k-loop streams
+    the packed weight tiles.
     """
-    M, K = x.shape
     N = w_packed.shape[1]
-    bn = min(bn, N)
     sp = describe_qgemv(x.shape, w_packed.shape, scales.shape, bits=bits,
-                        bn=bn, x_bytes=x.dtype.itemsize)
-    per, bk, nk = sp.meta["per"], sp.meta["bk"], sp.meta["nk"]
-    G = scales.shape[0]
-
-    grid = sp.grid
-    return pl.pallas_call(
-        functools.partial(_qgemv_kernel, bits=bits, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((M, bk), lambda j, k: (0, k)),
-            pl.BlockSpec((bk // per, bn), lambda j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda j, k: (k if G > 1 else 0, j)),
-        ],
-        out_specs=pl.BlockSpec((M, bn), lambda j, k: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((M, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w_packed, scales)
-
-
-def _qmm_grouped_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits: int,
-                        nk: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    codes = _unpack_tile(w_ref[0], bits)  # (bk, bn)
-    part = jax.lax.dot(x_ref[0].astype(jnp.float32), codes,
-                       preferred_element_type=jnp.float32)
-    acc_ref[...] += part * s_ref[0].astype(jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+                        bn=min(bn, N), x_bytes=x.dtype.itemsize)
+    sk = scales.shape[0] > 1
+    return _launch(x, w_packed, scales, sp, bits=bits, interpret=interpret,
+                   index_maps=(
+                       lambda j, k: (0, 0, k),
+                       lambda j, k: (k, j),
+                       lambda j, k: (k if sk else 0, 0, j),
+                       lambda j, k: (0, j)))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "interpret"))
@@ -199,32 +175,18 @@ def qmatmul_grouped(x: Array, w_packed: Array, scales: Array, *, bits: int,
     The expert dim is the leading (outermost) grid axis, so each
     expert's packed codes stream through VMEM exactly once per call —
     the stacked node is consumed directly and no (E, K, N) dequantized
-    copy ever exists. Per-expert scheduling and the scale-after-dot
-    trick match :func:`qgemv`; M (tokens routed per expert) keeps the
-    true row count when it is at most one sublane tile.
+    copy ever exists. M (tokens routed per expert) keeps the true row
+    count when it is at most one sublane tile.
     """
-    E, M, K = x.shape
+    _, M, _ = x.shape
     N = w_packed.shape[2]
-    bm = min(bm, M)
-    bn = min(bn, N)
     sp = describe_qmatmul_grouped(x.shape, w_packed.shape, scales.shape,
-                                  bits=bits, bm=bm, bn=bn,
+                                  bits=bits, bm=min(bm, M), bn=min(bn, N),
                                   x_bytes=x.dtype.itemsize)
-    per, bk, nk = sp.meta["per"], sp.meta["bk"], sp.meta["nk"]
-    G = scales.shape[1]
-
-    grid = sp.grid
-    return pl.pallas_call(
-        functools.partial(_qmm_grouped_kernel, bits=bits, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, bk // per, bn), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, 1, bn),
-                         lambda e, i, j, k: (e, k if G > 1 else 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w_packed, scales)
+    sk = scales.shape[1] > 1
+    return _launch(x, w_packed, scales, sp, bits=bits, interpret=interpret,
+                   index_maps=(
+                       lambda e, i, j, k: (e, 0, i, k),
+                       lambda e, i, j, k: (e, k, j),
+                       lambda e, i, j, k: (e, k if sk else 0, 0, j),
+                       lambda e, i, j, k: (e, i, j)))

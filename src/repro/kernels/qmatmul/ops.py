@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.quantizer import pack_int
+from ..spec import qmm_row_tile
 from .kernel import qgemv, qmatmul, qmatmul_grouped
 from .ref import (qgemv_ref, qmatmul_ref, qmm_grouped_dense_ref,
                   qmm_grouped_ref)
@@ -219,7 +220,8 @@ def _qmm_2d(x2: Array, qw: QuantizedLinear, backend: str, tier: str) -> Array:
                     interpret=interpret)
     else:
         m = x2.shape[0]
-        bm = 128 if m % 128 == 0 else 8
+        bm = qmm_row_tile(m, qw.k, qw.scales.shape[0], bits=qw.bits, bn=bn,
+                          x_bytes=x2.dtype.itemsize)
         pad = (-m) % bm
         if pad:
             x2 = jnp.pad(x2, ((0, pad), (0, 0)))
@@ -253,12 +255,14 @@ def _qmm_grouped(x: Array, qw: QuantizedLinear, backend: str) -> Array:
         out = ref(xg, qw.packed, qw.scales, qw.bits)
     else:
         m = xg.shape[1]
-        bm = m if m <= DECODE_M_MAX else (128 if m % 128 == 0 else 8)
+        n = qw.packed.shape[-1]
+        bn = 128 if n >= 128 else n
+        bm = m if m <= DECODE_M_MAX else qmm_row_tile(
+            m, k, qw.scales.shape[1], bits=qw.bits, bn=bn,
+            x_bytes=xg.dtype.itemsize)
         pad = (-m) % bm
         if pad:
             xg = jnp.pad(xg, ((0, 0), (0, pad), (0, 0)))
-        n = qw.packed.shape[-1]
-        bn = 128 if n >= 128 else n
         qw, n = _pad_cols(qw, bn)
         out = qmatmul_grouped(xg, qw.packed, qw.scales, bits=qw.bits, bm=bm,
                               bn=bn, interpret=jax.default_backend() != "tpu")

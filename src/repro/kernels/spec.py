@@ -23,6 +23,13 @@ double-buffered (Pallas pipelines the HBM copies), the output block and
 scratch are single-buffered. ``VMEM_BUDGET_BYTES`` is the declared
 per-core budget the auditor enforces (16 MB on current TPUs, with a
 safety margin left to the compiler).
+
+Every spec also records each operand's full array shape, and
+:func:`check_block_tiling` applies the TPU lowering's block rule to it:
+a block's last two dims are multiples of (8, 128) or equal the array's
+dims (a rank-1 block spans the array or a multiple of 128 words). It is
+the rule Mosaic refuses a launch for, so a describe_* that passes here
+compiles there as far as block shapes go.
 """
 from __future__ import annotations
 
@@ -45,6 +52,11 @@ class KernelSpecError(ValueError):
     """
 
 
+class BlockTilingError(KernelSpecError):
+    """A block shape the TPU lowering refuses: its last two dims are
+    neither multiples of (8, 128) nor the array's own dims."""
+
+
 def _check(cond: bool, kernel: str, msg: str) -> None:
     if not cond:
         raise KernelSpecError(f"{kernel}: {msg}")
@@ -59,6 +71,7 @@ class KernelSpec:
     blocks: dict  # operand name -> (block shape, dtype bytes)
     scratch: dict  # scratch name -> (shape, dtype bytes)
     meta: dict  # kernel-specific derived tiling (bk, nk, ...)
+    arrays: dict = dataclasses.field(default_factory=dict)  # name -> shape
 
     @property
     def vmem_bytes(self) -> int:
@@ -83,38 +96,122 @@ class KernelSpec:
                f"{ {k: v[0] for k, v in self.blocks.items()} })")
 
 
+def check_block_tiling(spec: KernelSpec) -> KernelSpec:
+    """Apply the TPU lowering's block rule to every operand of ``spec``.
+
+    Rank >= 2: the last dim is a multiple of 128 or the array's last
+    dim, and the second-to-last a multiple of 8 or the array's. Rank 1:
+    the block spans the array or a multiple of 128 * (4 / dtype bytes)
+    elements. Raises :class:`BlockTilingError` naming the operand."""
+    for name, (block, nbytes) in spec.blocks.items():
+        array = spec.arrays.get(name)
+        if array is None:
+            continue
+        if len(block) == 1:
+            tile = 128 * max(1, 4 // nbytes)
+            ok = block[0] == array[0] or block[0] % tile == 0
+            rule = f"a multiple of {tile} or the array's {array[0]}"
+        else:
+            ok = ((block[-1] == array[-1] or block[-1] % 128 == 0)
+                  and (block[-2] == array[-2] or block[-2] % 8 == 0))
+            rule = "multiples of (8, 128) or the array's last two dims"
+        if not ok:
+            raise BlockTilingError(
+                f"{spec.kernel}: operand {name!r} block {tuple(block)} over "
+                f"array {tuple(array)} is not TPU-tileable (last dims must "
+                f"be {rule})")
+    return spec
+
+
+def aligned_tile(dim: int, cap: int, align: int) -> int:
+    """Largest divisor of ``dim`` that is <= ``cap`` and a multiple of
+    ``align``; ``dim`` itself (one block spanning the whole axis, which
+    the block rule always accepts) when no such divisor exists."""
+    for d in range(min(dim, cap) - min(dim, cap) % align, 0, -align):
+        if dim % d == 0:
+            return d
+    return dim
+
+
 def _bits_per(kernel: str, bits: int) -> int:
     _check(bits in (2, 4, 8), kernel,
            f"container bits must be 2, 4 or 8, got {bits}")
     return 8 // bits
 
 
-def largest_tile(dim: int, cap: int, multiple: int = 1) -> int:
-    """Largest divisor of ``dim`` that is <= ``cap`` and a multiple of
-    ``multiple``; when no such divisor exists, ``min(dim, cap)`` (the
-    caller's divisibility ``_check`` then fails with the shapes named).
+def _pick_k_step(kernel: str, K: int, G: int, per: int) -> tuple[int, int]:
+    """(bkp, nk): packed rows per k-step and the number of k-steps.
 
-    The shared tile-picker for dims real configs do NOT make powers of
-    two (d_model 3840, d_ff 10944, vocab 51865): a flat cap would leave
-    a ragged last step the kernels' BlockSpecs cannot express.
-    """
-    for d in range(min(dim, cap), 0, -1):
-        if dim % d == 0 and d % multiple == 0:
-            return d
-    return min(dim, cap)
+    The kernels take activations as ``per`` field planes (per, M, K/per)
+    (see ``qmatmul/kernel.py``), so a k-step's packed rows are the x
+    block's lane dim: a multiple of 128, or all K/per rows in one step.
+    A grouped step also covers whole scale groups (g/per packed rows
+    each), applying one scale row per group inside the step."""
+    rows = K // per
+    _check(rows * per == K, kernel,
+           f"K={K} is not a multiple of the packing factor per={per} "
+           f"({8 // per}-bit codes)")
+    _check(K % G == 0, kernel,
+           f"K={K} does not split into G={G} scale groups")
+    align = 128
+    if G > 1:
+        g = K // G
+        _check(g % per == 0, kernel,
+               f"scale group {g} is not a multiple of the packing factor "
+               f"per={per} ({8 // per}-bit codes)")
+        align = math.lcm(128, g // per)
+    bkp = aligned_tile(rows, 512, align)
+    return bkp, rows // bkp
 
 
-def _pick_bk(kernel: str, K: int, G: int, per: int) -> tuple[int, int]:
-    """(bk, nk): one scale group per k-step, or the largest <=512
-    divisor per-channel."""
-    bk = largest_tile(K, 512, per) if G == 1 else K // G
-    _check(K % bk == 0, kernel,
-           f"K={K} is not a multiple of the k-tile bk={bk} "
-           f"(scale groups G={G})")
-    _check(bk % per == 0, kernel,
-           f"k-tile bk={bk} is not a multiple of the packing factor "
-           f"per={per} ({8 // per}-bit codes)")
-    return bk, K // bk
+def _describe_qmm(name: str, x_shape, wp_shape, scales_shape, *, bits: int,
+                  bm: int, bn: int, x_bytes: int) -> KernelSpec:
+    """Shared tile math of the three qmm tiers; a leading expert dim on
+    every operand (stacked experts) becomes the outermost grid axis."""
+    per = _bits_per(name, bits)
+    lead = tuple(x_shape[:-2])
+    M, K = x_shape[-2:]
+    rows, N = wp_shape[-2:]
+    G = scales_shape[-2]
+    _check(tuple(wp_shape[:-2]) == lead and tuple(scales_shape[:-2]) == lead,
+           name, f"expert axes disagree: x {tuple(x_shape)}, codes "
+           f"{tuple(wp_shape)}, scales {tuple(scales_shape)}")
+    _check(rows * per == K, name,
+           f"packed rows {rows} x {per} values/byte != K={K} "
+           f"(codes {tuple(wp_shape)}, x {tuple(x_shape)}, bits={bits})")
+    _check(scales_shape[-1] == N, name,
+           f"scales {tuple(scales_shape)} do not span N={N} columns")
+    bkp, nk = _pick_k_step(name, K, G, per)
+    _check(M % bm == 0, name, f"M={M} is not a multiple of bm={bm}")
+    _check(N % bn == 0, name, f"N={N} is not a multiple of bn={bn}")
+    ng = G // nk if G > 1 else 1  # scale rows (groups) per k-step
+    one = (1,) * len(lead)
+    return check_block_tiling(KernelSpec(
+        kernel=name, grid=(*lead, M // bm, N // bn, nk),
+        blocks={"x": ((*one, per, bm, bkp), x_bytes),
+                "w": ((*one, bkp, bn), 1),
+                "scales": ((*one, ng, 1, bn), 4),
+                "out": ((*one, bm, bn), x_bytes)},
+        arrays={"x": (*lead, per, M, rows), "w": (*lead, rows, N),
+                "scales": (*lead, G, 1, N), "out": (*lead, M, N)},
+        scratch={"acc": ((bm, bn), 4)},
+        meta={"bk": bkp * per, "bkp": bkp, "nk": nk, "ng": ng, "bm": bm,
+              "bn": bn, "per": per}))
+
+
+def qmm_row_tile(M: int, K: int, G: int, *, bits: int, bn: int,
+                 x_bytes: int = 4) -> int:
+    """Rows per ``qmatmul``/``qmatmul_grouped`` program: 128 when M tiles
+    by it, else one 8-row sublane tile (the caller zero-pads M), halved
+    while the launch overruns the VMEM budget — a k-step spans all K/per
+    packed rows when they have no 128-lane divisor (d_ff 10944)."""
+    bm = 128 if M % 128 == 0 else 8
+    wp = (K // _bits_per("qmatmul", bits), bn)
+    while bm > 8 and _describe_qmm(
+            "qmatmul", (bm, K), wp, (G, bn), bits=bits, bm=bm, bn=bn,
+            x_bytes=x_bytes).vmem_bytes > VMEM_BUDGET_BYTES:
+        bm //= 2
+    return bm
 
 
 def describe_qmatmul(x_shape, wp_shape, scales_shape, *, bits: int,
@@ -124,25 +221,8 @@ def describe_qmatmul(x_shape, wp_shape, scales_shape, *, bits: int,
     x (M, K) @ dequant(wp (K*bits/8, N), scales (K/G, N)) -> (M, N),
     grid (M/bm, N/bn, nk).
     """
-    name = "qmatmul"
-    per = _bits_per(name, bits)
-    M, K = x_shape
-    rows, N = wp_shape
-    G = scales_shape[0]
-    _check(rows * per == K, name,
-           f"packed rows {rows} x {per} values/byte != K={K} "
-           f"(codes {tuple(wp_shape)}, x {tuple(x_shape)}, bits={bits})")
-    _check(scales_shape[1] == N, name,
-           f"scales {tuple(scales_shape)} do not span N={N} columns")
-    bk, nk = _pick_bk(name, K, G, per)
-    _check(M % bm == 0, name, f"M={M} is not a multiple of bm={bm}")
-    _check(N % bn == 0, name, f"N={N} is not a multiple of bn={bn}")
-    return KernelSpec(
-        kernel=name, grid=(M // bm, N // bn, nk),
-        blocks={"x": ((bm, bk), x_bytes), "w": ((bk // per, bn), 1),
-                "scales": ((1, bn), 4), "out": ((bm, bn), x_bytes)},
-        scratch={"acc": ((bm, bn), 4)},
-        meta={"bk": bk, "nk": nk, "bm": bm, "bn": bn, "per": per})
+    return _describe_qmm("qmatmul", x_shape, wp_shape, scales_shape,
+                         bits=bits, bm=bm, bn=bn, x_bytes=x_bytes)
 
 
 def describe_qgemv(x_shape, wp_shape, scales_shape, *, bits: int,
@@ -152,24 +232,9 @@ def describe_qgemv(x_shape, wp_shape, scales_shape, *, bits: int,
     The whole M extent (decode batch rows) is one skinny block; grid
     (N/bn, nk) with the (M, bn) accumulator VMEM-resident.
     """
-    name = "qgemv"
-    per = _bits_per(name, bits)
-    M, K = x_shape
-    rows, N = wp_shape
-    G = scales_shape[0]
-    _check(rows * per == K, name,
-           f"packed rows {rows} x {per} values/byte != K={K} "
-           f"(codes {tuple(wp_shape)}, x {tuple(x_shape)}, bits={bits})")
-    _check(scales_shape[1] == N, name,
-           f"scales {tuple(scales_shape)} do not span N={N} columns")
-    bk, nk = _pick_bk(name, K, G, per)
-    _check(N % bn == 0, name, f"N={N} is not a multiple of bn={bn}")
-    return KernelSpec(
-        kernel=name, grid=(N // bn, nk),
-        blocks={"x": ((M, bk), x_bytes), "w": ((bk // per, bn), 1),
-                "scales": ((1, bn), 4), "out": ((M, bn), x_bytes)},
-        scratch={"acc": ((M, bn), 4)},
-        meta={"bk": bk, "nk": nk, "bn": bn, "per": per})
+    sp = _describe_qmm("qgemv", x_shape, wp_shape, scales_shape, bits=bits,
+                       bm=x_shape[0], bn=bn, x_bytes=x_bytes)
+    return dataclasses.replace(sp, grid=sp.grid[1:])
 
 
 def describe_qmatmul_grouped(x_shape, wp_shape, scales_shape, *, bits: int,
@@ -179,28 +244,15 @@ def describe_qmatmul_grouped(x_shape, wp_shape, scales_shape, *, bits: int,
     x (E, M, K) @ dequant((E, K*bits/8, N)) -> (E, M, N), expert-major
     grid (E, M/bm, N/bn, nk).
     """
-    name = "qmatmul_grouped"
-    per = _bits_per(name, bits)
-    E, M, K = x_shape
-    rows, N = wp_shape[1], wp_shape[2]
-    G = scales_shape[1]
-    _check(wp_shape[0] == E and scales_shape[0] == E, name,
-           f"expert axes disagree: x E={E}, codes {tuple(wp_shape)}, "
-           f"scales {tuple(scales_shape)}")
-    _check(rows * per == K, name,
-           f"packed rows {rows} x {per} values/byte != K={K} "
-           f"(codes {tuple(wp_shape)}, x {tuple(x_shape)}, bits={bits})")
-    _check(scales_shape[2] == N, name,
-           f"scales {tuple(scales_shape)} do not span N={N} columns")
-    bk, nk = _pick_bk(name, K, G, per)
-    _check(M % bm == 0, name, f"M={M} is not a multiple of bm={bm}")
-    _check(N % bn == 0, name, f"N={N} is not a multiple of bn={bn}")
-    return KernelSpec(
-        kernel=name, grid=(E, M // bm, N // bn, nk),
-        blocks={"x": ((1, bm, bk), x_bytes), "w": ((1, bk // per, bn), 1),
-                "scales": ((1, 1, bn), 4), "out": ((1, bm, bn), x_bytes)},
-        scratch={"acc": ((bm, bn), 4)},
-        meta={"bk": bk, "nk": nk, "bm": bm, "bn": bn, "per": per})
+    return _describe_qmm("qmatmul_grouped", x_shape, wp_shape, scales_shape,
+                         bits=bits, bm=bm, bn=bn, x_bytes=x_bytes)
+
+
+def kv_stream_tile(S: int) -> int:
+    """Cache rows per ``kv_decode`` step: the scale and position blocks
+    put the stream on lanes, so a multiple of 128 (at most 512) that
+    divides S, else all S rows in one step."""
+    return aligned_tile(S, 512, 128)
 
 
 def describe_kv_decode(q_shape, k8_shape, *, bs: int,
@@ -208,7 +260,9 @@ def describe_kv_decode(q_shape, k8_shape, *, bs: int,
     """Validate + describe a ``kv_decode`` (int8-KV attention) launch.
 
     q (B, H, hd) over int8 caches (B, S, K_heads, hd); grid (B, K, S/bs)
-    with the (G, hd) query group resident while S streams.
+    with the (G, hd) query group resident while S streams. Scales and
+    positions ride lane-major ((B, K, 1, S) and (B, 1, S)); ``cur_pos``
+    is scalar-prefetched into SMEM and takes no VMEM block.
     """
     name = "kv_decode"
     B, H, hd = q_shape
@@ -220,14 +274,31 @@ def describe_kv_decode(q_shape, k8_shape, *, bs: int,
     _check(S % bs == 0, name,
            f"cache length S={S} is not a multiple of the stream tile "
            f"bs={bs} (cache {tuple(k8_shape)})")
-    return KernelSpec(
+    return check_block_tiling(KernelSpec(
         kernel=name, grid=(B, K, S // bs),
         blocks={"q": ((1, 1, G, hd), q_bytes), "k": ((1, 1, bs, hd), 1),
-                "v": ((1, 1, bs, hd), 1), "kscale": ((1, 1, bs), 4),
-                "vscale": ((1, 1, bs), 4), "kpos": ((1, bs), 4),
-                "cur": ((1,), 4), "out": ((1, 1, G, hd), q_bytes)},
+                "v": ((1, 1, bs, hd), 1), "kscale": ((1, 1, 1, bs), 4),
+                "vscale": ((1, 1, 1, bs), 4), "kpos": ((1, 1, bs), 4),
+                "out": ((1, 1, G, hd), q_bytes)},
+        arrays={"q": (B, K, G, hd), "k": (B, K, S, hd), "v": (B, K, S, hd),
+                "kscale": (B, K, 1, S), "vscale": (B, K, 1, S),
+                "kpos": (B, 1, S), "out": (B, K, G, hd)},
         scratch={"m": ((G, 1), 4), "l": ((G, 1), 4), "acc": ((G, hd), 4)},
-        meta={"bs": bs, "ns": S // bs, "G": G})
+        meta={"bs": bs, "ns": S // bs, "G": G}))
+
+
+def fakequant_tiles(K: int, N: int) -> tuple[int, int]:
+    """(bk, bn) for a ``fakequant`` launch over a (K, N) weight: at most
+    256 x 256, with bk halved while a full-width bn (N without a 128-lane
+    divisor) overruns the VMEM budget."""
+    bn = aligned_tile(N, 256, 128)
+    cap = 256
+    while True:
+        bk = aligned_tile(K, cap, 8)
+        if cap <= 8 or describe_fakequant(
+                (K, N), (1, N), bk=bk, bn=bn).vmem_bytes <= VMEM_BUDGET_BYTES:
+            return bk, bn
+        cap //= 2
 
 
 def describe_fakequant(w_shape, scale_shape, *, bk: int, bn: int,
@@ -245,10 +316,12 @@ def describe_fakequant(w_shape, scale_shape, *, bk: int, bn: int,
     _check(scale_shape[0] in (1, K) and scale_shape[1] == N, name,
            f"scale {tuple(scale_shape)} must be (1, {N}) or ({K}, {N})")
     per_row = scale_shape[0] != 1
-    return KernelSpec(
+    return check_block_tiling(KernelSpec(
         kernel=name, grid=(K // bk, N // bn),
         blocks={"w": ((bk, bn), w_bytes), "v": ((bk, bn), w_bytes),
                 "scale": ((bk if per_row else 1, bn), 4),
                 "out": ((bk, bn), w_bytes)},
+        arrays={"w": (K, N), "v": (K, N), "scale": tuple(scale_shape),
+                "out": (K, N)},
         scratch={},
-        meta={"bk": bk, "bn": bn, "per_row": per_row})
+        meta={"bk": bk, "bn": bn, "per_row": per_row}))

@@ -31,6 +31,7 @@ from ..data import Corpus, CorpusConfig
 from ..deploy import (ArtifactMismatchError, QuantizedArtifact, rtn_artifact,
                       tree_bytes)
 from ..models import get_model
+from .compile_cache import use_compile_cache
 
 
 def parse_args(argv=None):
@@ -266,6 +267,7 @@ def _run_once(model, params, batch, args, hook=None, tag="fp"):
 
 def main(argv=None, params=None):
     args = parse_args(argv)
+    use_compile_cache()
     cfg, model = get_model(args.arch, reduced=args.reduced)
     if params is None:
         params = model.init(jax.random.PRNGKey(args.seed))

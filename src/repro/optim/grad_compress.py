@@ -20,21 +20,11 @@ from __future__ import annotations
 from functools import partial
 from typing import Any
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import adam
-
-# jax >= 0.6 exposes jax.shard_map (check_vma=); 0.4.x has the
-# experimental module (check_rep=). Resolve both once here.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
 
 Params = Any
 
@@ -74,11 +64,11 @@ def make_dp_train_step(model, mesh: Mesh, acfg: adam.AdamConfig,
             return params2, opt2, err2, loss
 
         rep = P()
-        return _shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(rep, rep, rep, P(axis)),
             out_specs=(rep, rep, rep, rep),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )(params, opt_state, err, batch)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))

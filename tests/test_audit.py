@@ -24,9 +24,12 @@ from repro.analysis.audit.rules import (AuditProgram, Violation,
                                         count_io_aliases, iter_jaxprs,
                                         registered_rules, run_program_rules)
 from repro.deploy import dequant_leaf, rtn_pack_leaf
-from repro.kernels.spec import (VMEM_BUDGET_BYTES, KernelSpecError,
+from repro.kernels.spec import (VMEM_BUDGET_BYTES, BlockTilingError,
+                                KernelSpecError, aligned_tile,
+                                describe_fakequant, describe_kv_decode,
                                 describe_qgemv, describe_qmatmul,
-                                describe_qmatmul_grouped, largest_tile)
+                                describe_qmatmul_grouped, fakequant_tiles,
+                                qmm_row_tile)
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -224,12 +227,40 @@ def test_registered_configs_sweep_clean():
     assert got == [], [str(v) for v in got]
 
 
-def test_largest_tile_picks_divisors():
-    assert largest_tile(3840, 512) == 480
-    assert largest_tile(512, 512) == 512
-    assert largest_tile(10944, 256) == 228
-    assert largest_tile(3840, 512, 2) == 480
-    assert largest_tile(7, 4) == 1
+@pytest.mark.parametrize("describe,operand", [
+    # a 64-row KV stream tile: scale and position blocks 64 lanes wide
+    (lambda: describe_kv_decode((8, 12, 64), (8, 512, 12, 64), bs=64),
+     "kscale"),
+    # a 4-row fakequant tile: the sublane dim is not a multiple of 8
+    (lambda: describe_fakequant((768, 768), (1, 768), bk=4, bn=128), "w"),
+], ids=["kv_decode_64_lanes", "fakequant_4_sublanes"])
+def test_seeded_untileable_block_fires(describe, operand):
+    """The chip's block rule: a block's last two dims are multiples of
+    (8, 128) or equal the array's. Mosaic refuses anything else, so the
+    CPU audit must too."""
+    with pytest.raises(BlockTilingError, match=f"operand '{operand}'"):
+        describe()
+
+
+def test_aligned_tile_picks_lane_aligned_divisors():
+    assert aligned_tile(3840, 512, 128) == 384
+    assert aligned_tile(512, 512, 128) == 512
+    assert aligned_tile(288, 512, 128) == 288  # no aligned divisor: whole
+    assert aligned_tile(10944, 256, 8) == 192
+    assert aligned_tile(10944, 256, 128) == 10944
+
+
+def test_row_and_fakequant_tiles_fit_vmem_for_untiled_k():
+    """d_ff 10944 has no 128-lane divisor, so a k-step or a fakequant
+    tile spans it whole; the pickers shrink the other tile to fit."""
+    bm = qmm_row_tile(128, 10944, 1, bits=4, bn=128)
+    assert 8 <= bm < 128
+    describe_qmatmul((128, 10944), (5472, 128), (1, 128), bits=4, bm=bm,
+                     bn=128).check_budget()
+    bk, bn = fakequant_tiles(2048, 10944)
+    assert bn == 10944 and bk % 8 == 0
+    describe_fakequant((2048, 10944), (1, 10944), bk=bk,
+                       bn=bn).check_budget()
 
 
 # ---------------------------------------------------------------------------

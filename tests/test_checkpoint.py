@@ -57,10 +57,12 @@ def test_elastic_restore_with_sharding(tmp_path):
     the path a different-size mesh uses after preemption/rescale."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import auto_mesh
+
     cm = CheckpointManager(tmp_path)
     t = tree()
     cm.save(5, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     r = cm.restore(5, t, shardings=sh)
     for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(r)):

@@ -1,6 +1,7 @@
 """Distribution layer: sharding rules (unit) + multi-device execution
 (subprocess with 8 placeholder devices) + gradient compression."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -9,14 +10,16 @@ from pathlib import Path
 import pytest
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
+# children see 8 placeholder CPU devices and never load the TPU runtime
+CHILD_ENV = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin:/usr/local/bin",
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+             "JAX_PLATFORMS": "cpu", "HOME": os.environ.get("HOME", "")}
 
 
 def run_subprocess(code: str) -> str:
-    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "HOME": "/root"}
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=900, env=env)
+                       capture_output=True, text=True, timeout=900,
+                       env=CHILD_ENV)
     assert r.returncode == 0, r.stderr[-3000:]
     return r.stdout
 
@@ -24,11 +27,12 @@ def run_subprocess(code: str) -> str:
 def test_param_specs_unit():
     code = """
     import jax
+    from repro.launch.mesh import auto_mesh
     from jax.sharding import PartitionSpec as P
     from repro.dist.sharding import Plan, pick_strategy
     from repro.models import get_config
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg = get_config("tinyllama_1_1b")
     plan = Plan(mesh=mesh, strategy="tp", cfg=cfg)
 
@@ -64,12 +68,13 @@ def test_packed_param_rules_unit():
     through params_sharding — incl. the int8-container (W3) fallback."""
     code = """
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     from repro import deploy
     from repro.dist.sharding import Plan
     from repro.launch import specs as specs_mod
     from repro.models import get_model
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg, model = get_model("tinyllama_1_1b", reduced=True)
     plan = Plan(mesh=mesh, strategy="tp", cfg=cfg)
     params = specs_mod.params_specs(model)
@@ -114,15 +119,12 @@ def test_packed_param_rules_unit():
 def test_dryrun_reduced_quant_decode_cell(tmp_path):
     """The dry-run CLI lowers + compiles a reduced --quant 4 decode cell
     (packed int codes through params_sharding) on an 8-device host mesh."""
-    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "HOME": "/root"}
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--reduced",
          "--arch", "tinyllama_1_1b", "--shape", "decode_32k",
          "--mesh", "single", "--quant", "4", "--group", "64",
          "--tag", "w4", "--out", str(tmp_path)],
-        capture_output=True, text=True, timeout=900, env=env)
+        capture_output=True, text=True, timeout=900, env=CHILD_ENV)
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(
         (tmp_path / "tinyllama_1_1b_decode_32k_single_w4.json").read_text())
@@ -146,6 +148,7 @@ def test_tp_train_step_executes():
     shardings as planned."""
     code = """
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     from repro.configs.base import ShapeSpec
     from repro.dist.sharding import Plan
     from repro.models import get_model
@@ -153,7 +156,7 @@ def test_tp_train_step_executes():
     from repro.optim import adam
 
     cfg, model = get_model("tinyllama_1_1b", reduced=True)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     plan = Plan(mesh=mesh, strategy="tp", cfg=cfg)
     shape = ShapeSpec("t", seq_len=32, global_batch=8, kind="train")
     low = steps_mod.make_train_step(model, plan, shape, remat="dots")
@@ -174,12 +177,13 @@ def test_tp_train_step_executes():
 def test_grad_compress_int8_matches_uncompressed():
     code = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import auto_mesh
     from repro.models import get_model
     from repro.optim import adam
     from repro.optim.grad_compress import init_error, make_dp_train_step
 
     cfg, model = get_model("brecq_lm_100m", reduced=True)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     acfg = adam.AdamConfig(lr=1e-3)
     params = model.init(jax.random.PRNGKey(0))
     opt = adam.init(params)
@@ -203,13 +207,14 @@ def test_dryrun_reduced_multi_mesh():
     the multi-pod path at toy scale."""
     code = """
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     from repro.configs.base import ShapeSpec
     from repro.dist.sharding import Plan
     from repro.models import get_model
     from repro.launch import steps as steps_mod
 
     cfg, model = get_model("deepseek_moe_16b", reduced=True)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = Plan(mesh=mesh, strategy="tp", cfg=cfg)
     shape = ShapeSpec("t", seq_len=32, global_batch=8, kind="train")
     compiled = steps_mod.make_train_step(model, plan, shape, remat="dots").lower().compile()

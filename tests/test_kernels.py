@@ -41,15 +41,17 @@ def test_qmatmul_vs_ref(rng, bits, M, K, N, group, dtype):
 
 @pytest.mark.parametrize("bits", [8, 4, 2])
 def test_unpack_tile_matches_unpack_int(rng, bits):
-    """int8 shift/mask unpack == the pure-jnp widening oracle."""
+    """In-kernel int32 shift/mask field unpack == the pure-jnp widening
+    oracle: field plane i holds K rows i, i + per, ... (the rows that
+    ``field_planes`` pairs it with)."""
     from repro.core.quantizer import pack_int, unpack_int
-    from repro.kernels.qmatmul.kernel import _unpack_tile
+    from repro.kernels.qmatmul.kernel import _unpack_fields
 
     K, N = 64, 128
     codes = jnp.asarray(
         rng.integers(-(2 ** (bits - 1)), 2 ** (bits - 1), size=(K, N)), jnp.int8)
     packed = pack_int(codes, bits)
-    got = _unpack_tile(packed, bits)
+    got = jnp.stack(_unpack_fields(packed, bits), axis=1).reshape(K, N)
     ref = unpack_int(packed, bits, K).astype(jnp.float32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(codes, np.float32))
