@@ -1,0 +1,11 @@
+import os
+import sys
+from pathlib import Path
+
+# the benchmark's tests run on the CPU; they describe no TPU
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH), str(BENCH.parent / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
