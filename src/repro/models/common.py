@@ -496,9 +496,9 @@ def paged_append(cache: Params, k: Array, v: Array, block_tables: Array,
     quantize through ``kernels.kvattn.quantize_kv`` on the way in.
     Distinct streams own distinct pages, so the scatter has no
     cross-stream collisions; all inactive-slot writes land on page 0.
+    Its operations carry the scope ``kv_write`` in their metadata.
     """
     B, C = positions.shape
-    rows = _page_rows(block_tables, positions, page_size).reshape(-1)
 
     def scat(pool, vals):
         flat = pool.reshape(pool.shape[0] * page_size, *pool.shape[2:])
@@ -506,16 +506,18 @@ def paged_append(cache: Params, k: Array, v: Array, block_tables: Array,
             vals.reshape(B * C, *vals.shape[2:]).astype(pool.dtype))
         return flat.reshape(pool.shape)
 
-    if "k_scale" in cache:
-        from ..kernels.kvattn.ops import quantize_kv
+    with jax.named_scope("kv_write"):
+        rows = _page_rows(block_tables, positions, page_size).reshape(-1)
+        if "k_scale" in cache:
+            from ..kernels.kvattn.ops import quantize_kv
 
-        k8, v8, ks, vs = quantize_kv(k, v)
-        return {"k_pages": scat(cache["k_pages"], k8),
-                "v_pages": scat(cache["v_pages"], v8),
-                "k_scale": scat(cache["k_scale"], ks),
-                "v_scale": scat(cache["v_scale"], vs)}
-    return {"k_pages": scat(cache["k_pages"], k),
-            "v_pages": scat(cache["v_pages"], v)}
+            k8, v8, ks, vs = quantize_kv(k, v)
+            return {"k_pages": scat(cache["k_pages"], k8),
+                    "v_pages": scat(cache["v_pages"], v8),
+                    "k_scale": scat(cache["k_scale"], ks),
+                    "v_scale": scat(cache["v_scale"], vs)}
+        return {"k_pages": scat(cache["k_pages"], k),
+                "v_pages": scat(cache["v_pages"], v)}
 
 
 def paged_view(cache: Params, block_tables: Array, page_size: int):
@@ -550,22 +552,25 @@ def paged_attend(q: Array, cache: Params, block_tables: Array,
     tokens (already appended). Single-token int8 decode goes through the
     ``kernels.kvattn`` int8 decode-attention kernel (``attend_int8``);
     chunked-prefill reads (C > 1) and float pools dequantize the
-    gathered view and share :func:`decode_attend`.
+    gathered view and share :func:`decode_attend`. Its operations carry
+    the scope ``kv_read`` in their metadata.
     """
-    gather, kpos = paged_view(cache, block_tables, page_size)
-    if "k_scale" in cache:
-        k8, v8 = gather(cache["k_pages"]), gather(cache["v_pages"])
-        ks = gather(cache["k_scale"]).astype(jnp.float32)
-        vs = gather(cache["v_scale"]).astype(jnp.float32)
-        if q.shape[1] == 1:
-            from ..kernels.kvattn.ops import attend_int8
+    with jax.named_scope("kv_read"):
+        gather, kpos = paged_view(cache, block_tables, page_size)
+        if "k_scale" in cache:
+            k8, v8 = gather(cache["k_pages"]), gather(cache["v_pages"])
+            ks = gather(cache["k_scale"]).astype(jnp.float32)
+            vs = gather(cache["v_scale"]).astype(jnp.float32)
+            if q.shape[1] == 1:
+                from ..kernels.kvattn.ops import attend_int8
 
-            out = attend_int8(q[:, 0], k8, v8, ks, vs, kpos, positions[:, 0],
-                              window=window, backend=backend)
-            return out[:, None]
-        k = (k8.astype(jnp.float32) * ks[..., None]).astype(q.dtype)
-        v = (v8.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
+                out = attend_int8(q[:, 0], k8, v8, ks, vs, kpos,
+                                  positions[:, 0], window=window,
+                                  backend=backend)
+                return out[:, None]
+            k = (k8.astype(jnp.float32) * ks[..., None]).astype(q.dtype)
+            v = (v8.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
+            return decode_attend(q, k, v, kpos, positions, window=window)
+        k = gather(cache["k_pages"]).astype(q.dtype)
+        v = gather(cache["v_pages"]).astype(q.dtype)
         return decode_attend(q, k, v, kpos, positions, window=window)
-    k = gather(cache["k_pages"]).astype(q.dtype)
-    v = gather(cache["v_pages"]).astype(q.dtype)
-    return decode_attend(q, k, v, kpos, positions, window=window)

@@ -35,6 +35,11 @@ Faults are isolated per stream: a non-finite logit row fails only that
 request (state ``failed``); everything else in the batch continues.
 ``drain()`` is the graceful way out — stop admission, finish (or
 preempt-and-report) in-flight work, return per-request statuses.
+
+Each tick's phases are named host spans (``SPANS``) and land in a
+``jax.profiler`` trace when one is recording; with no profiler they cost
+about a microsecond each. ``docs/serving.md`` ("Tracing the engine")
+draws the tree and says what each phase covers.
 """
 from __future__ import annotations
 
@@ -46,6 +51,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..launch.watchdog import StepWatchdog
 from ..models.common import NO_QUANT, PAGED_KV_DTYPES
@@ -54,6 +60,17 @@ from .pages import PagePool, PagePoolExhausted
 Array = jax.Array
 
 OVERCOMMIT_MODES = ("none", "prompt")
+
+# host spans of one tick: serve.tick holds the others. A *.run span only
+# dispatches its program; the wait for the device is in *.fetch.
+SPANS = ("serve.tick", "serve.expire", "serve.admit",
+         "serve.prefill.stage", "serve.prefill.run", "serve.prefill.fetch",
+         "serve.prefill.sample",
+         "serve.decode.stage", "serve.decode.run", "serve.decode.fetch",
+         "serve.decode.sample")
+(TICK, EXPIRE, ADMIT,
+ PREFILL_STAGE, PREFILL_RUN, PREFILL_FETCH, PREFILL_SAMPLE,
+ DECODE_STAGE, DECODE_RUN, DECODE_FETCH, DECODE_SAMPLE) = SPANS
 
 
 class RequestRejected(ValueError):
@@ -189,10 +206,10 @@ class ServeEngine:
         self._pf_ptr = 0
         self._admit_seq = 0
         self._decode_ticks = 0
-        self.decode_tick_log: list[int] = []  # tick ids that ran a decode step
         self._tokens_generated = 0
-        self._occupancy: list[float] = []
-        self._resident: list[float] = []
+        self._occupancy_sum = 0.0     # over decode ticks
+        self._resident_sum = 0.0      # over decode ticks with a slotted stream
+        self._resident_n = 0
         self._peak_pages = 0
         self._wall_s = 0.0
         self._compile_s: Optional[float] = None
@@ -340,15 +357,18 @@ class ServeEngine:
     def step(self) -> bool:
         """One tick: expire, admit, one prefill chunk, one batched decode."""
         self._ensure_compiled()
-        self._watchdog.start()
         t0 = time.perf_counter()
-        self._expire_deadlines()
-        self._admit()
-        did = self._prefill_one()
-        did = self._decode_all() or did
-        self._peak_pages = max(self._peak_pages, self.pool.pages_in_use)
-        self._wall_s += time.perf_counter() - t0
-        self._watchdog.stop(self.tick)
+        with TraceAnnotation(TICK, tick=self.tick):
+            with TraceAnnotation(EXPIRE):
+                self._expire_deadlines()
+            with TraceAnnotation(ADMIT):
+                self._admit()
+            did = self._prefill_one()
+            did = self._decode_all() or did
+            self._peak_pages = max(self._peak_pages, self.pool.pages_in_use)
+        dt = time.perf_counter() - t0
+        self._wall_s += dt
+        self._watchdog.observe(dt, self.tick)
         self.tick += 1
         return did or self.pending()
 
@@ -445,12 +465,13 @@ class ServeEngine:
             "wall_s": self._wall_s,
             "compile_s": self._compile_s or 0.0,
             "sustained_tok_s": toks / self._wall_s if self._wall_s else 0.0,
-            "mean_slot_occupancy": (float(np.mean(self._occupancy))
-                                    if self._occupancy else 0.0),
+            "mean_slot_occupancy": (self._occupancy_sum / self._decode_ticks
+                                    if self._decode_ticks else 0.0),
             "bytes_per_page": self.bytes_per_page,
             "peak_pages_in_use": self._peak_pages,
             "mean_resident_kv_bytes_per_stream": (
-                float(np.mean(self._resident)) if self._resident else 0.0),
+                self._resident_sum / self._resident_n
+                if self._resident_n else 0.0),
             "kv_dtype": self.cfg.kv_dtype,
             "page_size": self.cfg.page_size,
             "num_slots": self.cfg.num_slots,
@@ -600,16 +621,19 @@ class ServeEngine:
         C = self.cfg.prefill_chunk
         src = req.prefill_src if req.prefill_src is not None else req.prompt
         off = req.prefill_off
-        chunk = src[off:off + C]
-        n_real = len(chunk)
-        if n_real < C:  # ragged tail: pads write to the sink / dead rows
-            chunk = np.pad(chunk, (0, C - n_real))
-        self._ensure_pages(req, off + n_real - 1)
-        s = req.slot
-        logits, self.cache = self._chunk_c(
-            self.params, jnp.asarray(chunk[None]), self.cache,
-            jnp.full((1,), off, jnp.int32),
-            jnp.asarray(self.block_tables[s:s + 1]))
+        with TraceAnnotation(PREFILL_STAGE, uid=req.uid):
+            chunk = src[off:off + C]
+            n_real = len(chunk)
+            if n_real < C:  # ragged tail: pads write to the sink / dead rows
+                chunk = np.pad(chunk, (0, C - n_real))
+            self._ensure_pages(req, off + n_real - 1)
+            s = req.slot
+            tok = jnp.asarray(chunk[None])
+            pos = jnp.full((1,), off, jnp.int32)
+            bt = jnp.asarray(self.block_tables[s:s + 1])
+        with TraceAnnotation(PREFILL_RUN):
+            logits, self.cache = self._chunk_c(self.params, tok, self.cache,
+                                               pos, bt)
         req.prefill_off = off + n_real
         if req.preemptions:
             self._replay_chunks += 1
@@ -621,17 +645,19 @@ class ServeEngine:
                 req.state = "decode"
                 self._log("resume", req.uid)
                 return
-            lg = np.asarray(logits[0, n_real - 1])
-            if not np.isfinite(lg).all():
-                self._fail(req, "non-finite logits at prefill")
-                return
-            req.generated.append(int(lg.argmax()))
-            if self.cfg.record_logits:
-                req.logits.append(lg)
-            req.state = "decode"
-            self._tokens_generated += 1
-            self._log("first_token", req.uid)
-            self._maybe_finish(req)
+            with TraceAnnotation(PREFILL_FETCH):
+                lg = np.asarray(logits[0, n_real - 1])
+            with TraceAnnotation(PREFILL_SAMPLE):
+                if not np.isfinite(lg).all():
+                    self._fail(req, "non-finite logits at prefill")
+                    return
+                req.generated.append(int(lg.argmax()))
+                if self.cfg.record_logits:
+                    req.logits.append(lg)
+                req.state = "decode"
+                self._tokens_generated += 1
+                self._log("first_token", req.uid)
+                self._maybe_finish(req)
 
     def _decode_all(self) -> bool:
         cfg = self.cfg
@@ -640,54 +666,61 @@ class ServeEngine:
                     and self.slot_req[s].state == "decode"]
         if not decoding:
             return False
-        tokens = np.zeros((cfg.num_slots, 1), np.int32)
-        pos = np.zeros((cfg.num_slots,), np.int32)
-        # non-decoding slots get an all--1 block table row so their dummy
-        # writes land on the sink page instead of a prefilling stream's KV
-        bt = np.full_like(self.block_tables, -1)
-        staged = []
-        for s in decoding:
-            req = self.slot_req[s]
-            if req is None or req.state != "decode":
-                continue  # preempted this tick by an earlier slot's page grab
-            pos[s] = len(req.prompt) + len(req.generated) - 1
-            tokens[s, 0] = req.generated[-1]
-            self._ensure_pages(req, int(pos[s]))
-            bt[s] = self.block_tables[s]
-            staged.append(s)
-        # a later slot's _ensure_pages may have preempted an earlier
-        # staged one — its pages are gone, so route its write to the sink
-        # and drop it from this tick's batch (it re-prefills on readmit)
-        live = [s for s in staged if self.slot_req[s] is not None
-                and self.slot_req[s].state == "decode"]
-        for s in set(staged) - set(live):
-            bt[s] = -1
-        if not live:
-            return False
-        logits, self.cache = self._decode_c(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.asarray(pos), jnp.asarray(bt))
-        lg = np.asarray(logits)
-        n_ok = 0
-        for s in live:
-            req = self.slot_req[s]
-            row = lg[s]
-            if not np.isfinite(row).all():
-                self._fail(req, "non-finite logits")
-                continue
-            req.generated.append(int(row.argmax()))
-            if self.cfg.record_logits:
-                req.logits.append(row)
-            n_ok += 1
-            self._maybe_finish(req)
-        self._decode_ticks += 1
-        self.decode_tick_log.append(self.tick)
-        self._tokens_generated += n_ok
-        self._occupancy.append(len(live) / cfg.num_slots)
-        active = sum(r is not None for r in self.slot_req)
-        if active:
-            self._resident.append(
-                self.pool.pages_in_use * self.bytes_per_page / active)
+        with TraceAnnotation(DECODE_STAGE):
+            tokens = np.zeros((cfg.num_slots, 1), np.int32)
+            pos = np.zeros((cfg.num_slots,), np.int32)
+            # non-decoding slots get an all--1 block table row so their
+            # dummy writes land on the sink page instead of a prefilling
+            # stream's KV
+            bt = np.full_like(self.block_tables, -1)
+            staged = []
+            for s in decoding:
+                req = self.slot_req[s]
+                if req is None or req.state != "decode":
+                    continue  # preempted this tick by an earlier slot's page grab
+                pos[s] = len(req.prompt) + len(req.generated) - 1
+                tokens[s, 0] = req.generated[-1]
+                self._ensure_pages(req, int(pos[s]))
+                bt[s] = self.block_tables[s]
+                staged.append(s)
+            # a later slot's _ensure_pages may have preempted an earlier
+            # staged one — its pages are gone, so route its write to the
+            # sink and drop it from this tick's batch (it re-prefills on
+            # readmit)
+            live = [s for s in staged if self.slot_req[s] is not None
+                    and self.slot_req[s].state == "decode"]
+            for s in set(staged) - set(live):
+                bt[s] = -1
+            if not live:
+                return False
+            tokens, pos, bt = (jnp.asarray(tokens), jnp.asarray(pos),
+                               jnp.asarray(bt))
+        with TraceAnnotation(DECODE_RUN, rows=len(live)):
+            logits, self.cache = self._decode_c(self.params, tokens,
+                                                self.cache, pos, bt)
+        with TraceAnnotation(DECODE_FETCH):
+            lg = np.asarray(logits)
+        with TraceAnnotation(DECODE_SAMPLE):
+            n_ok = 0
+            for s in live:
+                req = self.slot_req[s]
+                row = lg[s]
+                if not np.isfinite(row).all():
+                    self._fail(req, "non-finite logits")
+                    continue
+                req.generated.append(int(row.argmax()))
+                if self.cfg.record_logits:
+                    req.logits.append(row)
+                n_ok += 1
+                self._maybe_finish(req)
+            self._decode_ticks += 1
+            self._tokens_generated += n_ok
+            self._occupancy_sum += len(live) / cfg.num_slots
+            active = sum(r is not None for r in self.slot_req)
+            if active:
+                self._resident_sum += (self.pool.pages_in_use
+                                       * self.bytes_per_page / active)
+                self._resident_n += 1
         return True
 
     def _maybe_finish(self, req: Request) -> None:

@@ -30,8 +30,10 @@ def _prompts(vocab, seed=11):
 
 
 def _run_staggered(model, params, kv_dtype, *, quant=None, cancel_uid=None,
-                   cancel_at_len=2):
-    """All requests in flight together, admitted on their arrival ticks."""
+                   cancel_at_len=2, decode_ticks=None):
+    """All requests in flight together, admitted on their arrival ticks.
+    ``decode_ticks``: a list that collects the ticks that ran a decode
+    step, seen through ``metrics()["decode_ticks"]``."""
     from repro.models.common import NO_QUANT
 
     eng = ServeEngine(model, params, EngineConfig(kv_dtype=kv_dtype, **ECFG),
@@ -42,7 +44,10 @@ def _run_staggered(model, params, kv_dtype, *, quant=None, cancel_uid=None,
         while nxt < len(prompts) and ARRIVALS[nxt] <= eng.tick:
             eng.submit(prompts[nxt], MAX_NEW[nxt], uid=nxt)
             nxt += 1
+        ran = eng.metrics()["decode_ticks"]
         eng.step()
+        if decode_ticks is not None and eng.metrics()["decode_ticks"] > ran:
+            decode_ticks.append(eng.tick - 1)
         for s, req in enumerate(eng.slot_req):
             if req is not None:
                 slots_seen.setdefault(req.uid, s)
@@ -152,17 +157,109 @@ def test_chunked_prefill_interleaves_decode(tiny_trained):
     """A long prompt prefills in chunks WHILE other streams decode: a
     decode step runs on a tick strictly between two of its chunks."""
     _, model, params, _, _, _ = tiny_trained
-    eng, _ = _run_staggered(model, params, "int8")
+    decode_ticks = []
+    eng, _ = _run_staggered(model, params, "int8", decode_ticks=decode_ticks)
     # uid 3: prompt 17 over chunk 8 -> 3 prefill_chunk events
     chunk_ticks = [t for t, ev, uid in eng.events
                    if ev == "prefill_chunk" and uid == 3]
     assert len(chunk_ticks) == 3
     assert chunk_ticks[0] < chunk_ticks[-1], "chunks all ran in one tick"
-    between = [t for t in eng.decode_tick_log
+    between = [t for t in decode_ticks
                if chunk_ticks[0] <= t < chunk_ticks[-1]]
     assert between, (
         f"no decode step between prefill chunks {chunk_ticks} "
-        f"(decode ticks: {eng.decode_tick_log})")
+        f"(decode ticks: {decode_ticks})")
+
+
+def _serve_spans(trace_dir):
+    """(name, start ns, end ns, stats) of every ``serve.*`` host event in
+    the newest profile under ``trace_dir``, in start order."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("serve.")]
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.fixture(scope="module")
+def traced_run(tiny_trained, tmp_path_factory):
+    """The staggered int8 schedule served while the profiler records, and
+    the engine's spans from that trace."""
+    import jax
+
+    _, model, params, _, _, _ = tiny_trained
+    trace_dir = str(tmp_path_factory.mktemp("engine_trace"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        eng, _ = _run_staggered(model, params, "int8")
+    finally:
+        jax.profiler.stop_trace()
+    return eng, _serve_spans(trace_dir)
+
+
+def _ticks(spans):
+    """Each serve.tick span with the phase spans that lie inside it."""
+    ticks = [s for s in spans if s[0] == "serve.tick"]
+    return [(t, [s for s in spans if s[0] != "serve.tick"
+                 and t[1] <= s[1] and s[2] <= t[2]]) for t in ticks]
+
+
+def test_engine_spans_are_declared(traced_run):
+    from repro.serve_engine.engine import SPANS
+
+    eng, spans = traced_run
+    assert {s[0] for s in spans} <= set(SPANS)
+    ticks = [s for s in spans if s[0] == "serve.tick"]
+    assert [int(s[3]["tick"]) for s in ticks] == list(range(eng.tick))
+    assert all("rows" in s[3] for s in spans if s[0] == "serve.decode.run")
+    assert all("uid" in s[3] for s in spans if s[0] == "serve.prefill.stage")
+
+
+def test_engine_phases_lie_inside_ticks(traced_run):
+    _, spans = traced_run
+    phases = [s for s in spans if s[0] != "serve.tick"]
+    inside = sum(len(p) for _, p in _ticks(spans))
+    assert phases and inside == len(phases)
+
+
+def test_engine_decode_tick_phase_order(traced_run):
+    """Every tick that decodes stages, dispatches, fetches and samples,
+    in that order; a prompt's last chunk fetches and samples its first
+    token before the decode phases start."""
+    eng, spans = traced_run
+    decoding = 0
+    for _, phases in _ticks(spans):
+        names = [p[0] for p in phases]
+        if "serve.decode.run" not in names:
+            continue
+        decoding += 1
+        dec = [n for n in names if n.startswith("serve.decode.")]
+        assert dec == ["serve.decode.stage", "serve.decode.run",
+                       "serve.decode.fetch", "serve.decode.sample"]
+        first = names.index("serve.decode.stage")
+        assert all(not n.startswith("serve.prefill.") for n in names[first:])
+    assert decoding == eng.metrics()["decode_ticks"]
+    fetches = [s for s in spans if s[0] == "serve.prefill.fetch"]
+    assert len(fetches) == len(eng.requests)   # one first token each
+
+
+def test_engine_tokens_same_with_profiler(traced_run, tiny_trained):
+    _, model, params, _, _, _ = tiny_trained
+    eng, _ = traced_run
+    plain, _ = _run_staggered(model, params, "int8")
+    assert _tokens(eng) == _tokens(plain)
+    assert eng.metrics()["mean_slot_occupancy"] == pytest.approx(
+        plain.metrics()["mean_slot_occupancy"])
 
 
 def test_no_page_leak_and_refcounts(tiny_trained):
