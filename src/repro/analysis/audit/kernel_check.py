@@ -5,7 +5,8 @@ No device and no weights: each arch's params tree comes from
 ``jax.eval_shape`` over ``deploy.pack.quantize_tree`` (shape-driven by
 design), then every packed leaf is swept through the same
 ``kernels.spec.describe_*`` functions the kernel wrappers call — with
-the same tile selection and ragged-N padding the ops layer applies
+the tiles ``kernels.spec.qmm_tiles`` picks for them and the ragged-N
+padding the ops layer applies
 (``qmatmul/ops.py`` ``_qmm_2d``/``_qmm_grouped``, ``kvattn/ops.py``
 ``attend_int8``). A launch the runtime would attempt that fails its
 tiling contract, or whose estimated VMEM exceeds
@@ -33,19 +34,11 @@ register_catalog_rule(
     "buffered input blocks + output/scratch) must stay under the "
     "declared per-core budget for every audited launch.")
 
-# Decode batch rows and canonical KV cache length for the sweep; bs/bm/bn
-# selection below mirrors the ops wrappers exactly.
+# Decode batch rows and canonical KV cache length for the sweep; tiles
+# come from the pickers the ops wrappers call.
 DECODE_M = 4
 PREFILL_M = 128
 KV_SEQ = 512
-
-
-def _bn(n: int) -> int:
-    return 128 if n >= 128 else n
-
-
-def _pad(n: int, b: int) -> int:
-    return n + (-n) % b
 
 
 def _iter_packed(fp, packed, path=()):
@@ -71,7 +64,7 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
     """Audit every launch the qmm dispatch would issue for one packed
     leaf (decode + prefill tiers; grouped for stacked experts)."""
     from ...kernels.spec import (describe_qgemv, describe_qmatmul,
-                                 describe_qmatmul_grouped, qmm_row_tile)
+                                 describe_qmatmul_grouped, qmm_tiles)
 
     # strip the scan-stack dim: the runtime slices one layer per step
     if len(wp_shape) >= 3 and len(qs_shape) == len(wp_shape):
@@ -83,36 +76,29 @@ def _sweep_leaf(arch: str, path: str, K: int, wp_shape, qs_shape,
              f"K={K} (codes {tuple(wp_shape)})")
         return
     bits = 8 // (K // rows)
-    bn = _bn(N)
-    npad = _pad(N, bn)
+    npad = N + (-N) % 128 if N >= 128 else N  # ops._pad_cols
     wp2 = (rows, npad)
     qs2 = (qs_shape[-2], npad)
     subject = f"{arch}:{path}"
-    try:
-        bm = qmm_row_tile(PREFILL_M, K, qs2[0], bits=bits, bn=bn)
-    except KernelSpecError as e:
-        emit("kernel_tile_divisibility", subject, str(e))
-        return
     launches = []
     if len(wp_shape) == 2:
         launches = [
-            ("decode", lambda: describe_qgemv(
-                (DECODE_M, K), wp2, qs2, bits=bits, bn=bn)),
-            ("prefill", lambda: describe_qmatmul(
-                (PREFILL_M, K), wp2, qs2, bits=bits, bm=bm, bn=bn)),
+            ("decode", DECODE_M, lambda m, bm, **t: describe_qgemv(
+                (m, K), wp2, qs2, bits=bits, **t)),
+            ("prefill", PREFILL_M, lambda m, **t: describe_qmatmul(
+                (m, K), wp2, qs2, bits=bits, **t)),
         ]
     elif len(wp_shape) == 3:
         E = wp_shape[0]
         wp3, qs3 = (E,) + wp2, (E,) + qs2
         launches = [
-            ("grouped-decode", lambda: describe_qmatmul_grouped(
-                (E, DECODE_M, K), wp3, qs3, bits=bits, bm=DECODE_M, bn=bn)),
-            ("grouped-prefill", lambda: describe_qmatmul_grouped(
-                (E, PREFILL_M, K), wp3, qs3, bits=bits, bm=bm, bn=bn)),
-        ]
-    for tier, describe in launches:
+            (f"grouped-{tier}", m, lambda m, **t: describe_qmatmul_grouped(
+                (E, m, K), wp3, qs3, bits=bits, **t))
+            for tier, m in (("decode", DECODE_M), ("prefill", PREFILL_M))]
+    for tier, m, describe in launches:
         try:
-            sp = describe()
+            bm, bkp, bn = qmm_tiles(m, K, npad, qs2[0], bits=bits)
+            sp = describe(m, bm=bm, bkp=bkp, bn=bn)
         except KernelSpecError as e:
             emit("kernel_tile_divisibility", f"{subject}[{tier}]", str(e))
             continue

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.quantizer import pack_int
-from ..spec import qmm_row_tile
+from ..spec import qmm_tiles
 from .kernel import qgemv, qmatmul, qmatmul_grouped
 from .ref import (qgemv_ref, qmatmul_ref, qmm_grouped_dense_ref,
                   qmm_grouped_ref)
@@ -194,11 +194,13 @@ def select_tier(m: int, qw: QuantizedLinear) -> str:
 
 
 def _pad_cols(qw: QuantizedLinear, bn: int) -> tuple[QuantizedLinear, int]:
-    """Zero-pad ragged N up to a multiple of ``bn`` (padded scales are
-    zero, so the extra columns cost nothing numerically and are sliced
-    off after the kernel)."""
+    """Zero-pad ragged N (at least ``bn`` wide) up to a multiple of
+    ``bn`` (padded scales are zero, so the extra columns cost nothing
+    numerically and are sliced off after the kernel). A narrower N is
+    one whole column block, and N a multiple of ``bn`` is left as it is:
+    the kernels pick a divisor of it."""
     n = qw.packed.shape[-1]
-    pad = (-n) % bn
+    pad = (-n) % bn if n >= bn else 0
     if not pad:
         return qw, n
     widths = [(0, 0)] * (qw.packed.ndim - 1) + [(0, pad)]
@@ -212,21 +214,20 @@ def _qmm_2d(x2: Array, qw: QuantizedLinear, backend: str, tier: str) -> Array:
         ref = qgemv_ref if tier == "decode" else qmatmul_ref
         return ref(x2, qw.packed, qw.scales, qw.bits)
     interpret = jax.default_backend() != "tpu"
-    n = qw.packed.shape[-1]
-    bn = 128 if n >= 128 else n
-    qw, n = _pad_cols(qw, bn)
+    qw, n = _pad_cols(qw, 128)
     if tier == "decode":
-        out = qgemv(x2, qw.packed, qw.scales, bits=qw.bits, bn=bn,
+        out = qgemv(x2, qw.packed, qw.scales, bits=qw.bits,
                     interpret=interpret)
     else:
         m = x2.shape[0]
-        bm = qmm_row_tile(m, qw.k, qw.scales.shape[0], bits=qw.bits, bn=bn,
-                          x_bytes=x2.dtype.itemsize)
+        bm, bkp, bn = qmm_tiles(m, qw.k, qw.packed.shape[-1],
+                                qw.scales.shape[0], bits=qw.bits,
+                                x_bytes=x2.dtype.itemsize)
         pad = (-m) % bm
         if pad:
             x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-        out = qmatmul(x2, qw.packed, qw.scales, bits=qw.bits, bm=bm, bn=bn,
-                      interpret=interpret)
+        out = qmatmul(x2, qw.packed, qw.scales, bits=qw.bits, bm=bm, bkp=bkp,
+                      bn=bn, interpret=interpret)
         if pad:
             out = out[:m]
     return out[:, :n] if out.shape[-1] != n else out
@@ -255,17 +256,15 @@ def _qmm_grouped(x: Array, qw: QuantizedLinear, backend: str) -> Array:
         out = ref(xg, qw.packed, qw.scales, qw.bits)
     else:
         m = xg.shape[1]
-        n = qw.packed.shape[-1]
-        bn = 128 if n >= 128 else n
-        bm = m if m <= DECODE_M_MAX else qmm_row_tile(
-            m, k, qw.scales.shape[1], bits=qw.bits, bn=bn,
-            x_bytes=xg.dtype.itemsize)
+        qw, n = _pad_cols(qw, 128)
+        bm, bkp, bn = qmm_tiles(m, k, qw.packed.shape[-1], qw.scales.shape[1],
+                                bits=qw.bits, x_bytes=xg.dtype.itemsize)
         pad = (-m) % bm
         if pad:
             xg = jnp.pad(xg, ((0, 0), (0, pad), (0, 0)))
-        qw, n = _pad_cols(qw, bn)
         out = qmatmul_grouped(xg, qw.packed, qw.scales, bits=qw.bits, bm=bm,
-                              bn=bn, interpret=jax.default_backend() != "tpu")
+                              bkp=bkp, bn=bn,
+                              interpret=jax.default_backend() != "tpu")
         out = out[:, :m, :n]
     nn = out.shape[-1]
     return jnp.moveaxis(out.reshape(e, -1, c, nn), 0, 1).reshape(*lead, e, c, nn)
@@ -290,9 +289,11 @@ def qmm(x: Array, qw: QuantizedLinear, *, backend: str = "auto") -> Array:
 
     Tier selection (:func:`select_tier`): M <= ``DECODE_M_MAX`` rows run
     the ``qgemv`` decode kernel at the true row count; larger M runs the
-    tiled prefill GEMM with ragged M zero-padded up to the 8/128 sublane
-    tile; 3-D stacked nodes run the grouped expert kernel. Ragged N is
-    zero-padded (zero scales) up to the lane tile and sliced back.
+    tiled prefill GEMM, as one row block up to ``spec.QMM_ONE_BLOCK_ROWS``
+    and with ragged M zero-padded up to the row tile above; 3-D stacked
+    nodes run the grouped expert kernel. Tiles come from
+    ``spec.qmm_tiles``. Ragged N is zero-padded (zero scales) up to the
+    lane tile and sliced back.
     """
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"

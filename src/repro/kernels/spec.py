@@ -19,8 +19,10 @@ Two consumers share it:
   mis-tiles (or a VMEM blow-up) the moment a kernel or config changes.
 
 The VMEM model is deliberately simple and documented: input blocks are
-double-buffered (Pallas pipelines the HBM copies), the output block and
-scratch are single-buffered. ``VMEM_BUDGET_BYTES`` is the declared
+double-buffered (Pallas pipelines the HBM copies), the output block,
+scratch and the values a kernel body keeps in VMEM (``temps``: qmm's
+unpacked field planes and partial sums) are single-buffered.
+``VMEM_BUDGET_BYTES`` is the declared
 per-core budget the auditor enforces (16 MB on current TPUs, with a
 safety margin left to the compiler).
 
@@ -34,6 +36,7 @@ compiles there as far as block shapes go.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 # Declared VMEM budget per program instance. TPU cores have ~16 MB of
@@ -72,16 +75,19 @@ class KernelSpec:
     scratch: dict  # scratch name -> (shape, dtype bytes)
     meta: dict  # kernel-specific derived tiling (bk, nk, ...)
     arrays: dict = dataclasses.field(default_factory=dict)  # name -> shape
+    # values the kernel body computes that live in VMEM (qmm's unpacked
+    # f32 field planes): name -> (shape, dtype bytes)
+    temps: dict = dataclasses.field(default_factory=dict)
 
     @property
     def vmem_bytes(self) -> int:
         """Estimated VMEM per program instance: double-buffered input
-        blocks + single-buffered output/scratch."""
+        blocks + single-buffered output/scratch/temps."""
         total = 0
         for name, (shape, nbytes) in self.blocks.items():
             mult = 1 if name.startswith("out") else 2
             total += mult * math.prod(shape) * nbytes
-        for shape, nbytes in self.scratch.values():
+        for shape, nbytes in (*self.scratch.values(), *self.temps.values()):
             total += math.prod(shape) * nbytes
         return total
 
@@ -139,35 +145,68 @@ def _bits_per(kernel: str, bits: int) -> int:
     return 8 // bits
 
 
-def _pick_k_step(kernel: str, K: int, G: int, per: int) -> tuple[int, int]:
-    """(bkp, nk): packed rows per k-step and the number of k-steps.
+# qmm tile targets. A grid step has a fixed cost of about 0.33 us on a
+# v5e (the time of ~270 KB at HBM speed), so a step moves up to
+# QMM_STEP_BYTES of packed weight. The kernel unpacks a step's codes
+# QMM_SLICE_BYTES at a time, so its f32 field planes stay a few MB
+# whatever the step. Up to QMM_ONE_BLOCK_ROWS activation rows are one
+# row block, so each packed weight tile streams once per call.
+QMM_STEP_BYTES = 1 << 20
+QMM_SLICE_BYTES = 128 << 10
+QMM_ONE_BLOCK_ROWS = 256
+
+
+def _k_layout(kernel: str, K: int, G: int, per: int) -> tuple[int, int]:
+    """(rows, align): the K/per packed rows and the multiple every k-step
+    and in-kernel row slice keeps.
 
     The kernels take activations as ``per`` field planes (per, M, K/per)
-    (see ``qmatmul/kernel.py``), so a k-step's packed rows are the x
-    block's lane dim: a multiple of 128, or all K/per rows in one step.
-    A grouped step also covers whole scale groups (g/per packed rows
-    each), applying one scale row per group inside the step."""
+    (see ``qmatmul/kernel.py``), so packed rows are the x block's lane
+    dim: a slice of them is a multiple of 128, or all K/per rows. A
+    grouped slice also covers whole scale groups (g/per packed rows
+    each), applying one scale row per group."""
     rows = K // per
     _check(rows * per == K, kernel,
            f"K={K} is not a multiple of the packing factor per={per} "
            f"({8 // per}-bit codes)")
     _check(K % G == 0, kernel,
            f"K={K} does not split into G={G} scale groups")
-    align = 128
-    if G > 1:
-        g = K // G
-        _check(g % per == 0, kernel,
-               f"scale group {g} is not a multiple of the packing factor "
-               f"per={per} ({8 // per}-bit codes)")
-        align = math.lcm(128, g // per)
-    bkp = aligned_tile(rows, 512, align)
-    return bkp, rows // bkp
+    if G == 1:
+        return rows, 128
+    g = K // G
+    _check(g % per == 0, kernel,
+           f"scale group {g} is not a multiple of the packing factor "
+           f"per={per} ({8 // per}-bit codes)")
+    return rows, math.lcm(128, g // per)
+
+
+def _aligned_divisors(dim: int, align: int) -> list[int]:
+    """Divisors of ``dim`` that are multiples of ``align``, largest
+    first; ``[dim]`` (one block spanning the axis) when there are none."""
+    return [d for d in range(dim - dim % align, 0, -align)
+            if dim % d == 0] or [dim]
+
+
+def _slice_rows(bkp: int, bn: int, align: int) -> int:
+    """Packed rows the kernel unpacks at a time inside a (bkp, bn) step:
+    the largest aligned divisor of bkp holding at most QMM_SLICE_BYTES
+    of codes, else the smallest."""
+    divs = _aligned_divisors(bkp, align)
+    return next((d for d in divs if d * bn <= QMM_SLICE_BYTES), divs[-1])
 
 
 def _describe_qmm(name: str, x_shape, wp_shape, scales_shape, *, bits: int,
-                  bm: int, bn: int, x_bytes: int) -> KernelSpec:
+                  bm: int, bkp: int, bn: int, x_bytes: int) -> KernelSpec:
     """Shared tile math of the three qmm tiers; a leading expert dim on
-    every operand (stacked experts) becomes the outermost grid axis."""
+    every operand (stacked experts) becomes the outermost grid axis.
+
+    The x block holds every packed row of its row block, fetched once
+    (``x_resident``), unless that overruns the VMEM budget; then it
+    walks k with the weight tile. Temps: the field planes of one
+    in-kernel row slice (int32 codes, ``per`` f32 planes, and a scale
+    plane when grouped), and a (bm, bn) f32 partial sum per slice, since
+    the kernel unrolls its slices and Mosaic keeps their results apart
+    (measured against the compiler's VMEM limit for a v5e)."""
     per = _bits_per(name, bits)
     lead = tuple(x_shape[:-2])
     M, K = x_shape[-2:]
@@ -181,71 +220,116 @@ def _describe_qmm(name: str, x_shape, wp_shape, scales_shape, *, bits: int,
            f"(codes {tuple(wp_shape)}, x {tuple(x_shape)}, bits={bits})")
     _check(scales_shape[-1] == N, name,
            f"scales {tuple(scales_shape)} do not span N={N} columns")
-    bkp, nk = _pick_k_step(name, K, G, per)
+    _, align = _k_layout(name, K, G, per)
+    _check(rows % bkp == 0 and (bkp % align == 0 or bkp == rows), name,
+           f"a k-step of bkp={bkp} packed rows must divide K/per={rows} "
+           f"in multiples of {align}, or span all of them")
     _check(M % bm == 0, name, f"M={M} is not a multiple of bm={bm}")
     _check(N % bn == 0, name, f"N={N} is not a multiple of bn={bn}")
+    nk = rows // bkp
     ng = G // nk if G > 1 else 1  # scale rows (groups) per k-step
+    rs = _slice_rows(bkp, bn, align)
     one = (1,) * len(lead)
-    return check_block_tiling(KernelSpec(
+    sp = KernelSpec(
         kernel=name, grid=(*lead, M // bm, N // bn, nk),
-        blocks={"x": ((*one, per, bm, bkp), x_bytes),
+        blocks={"x": ((*one, per, bm, rows), x_bytes),
                 "w": ((*one, bkp, bn), 1),
                 "scales": ((*one, ng, 1, bn), 4),
                 "out": ((*one, bm, bn), x_bytes)},
         arrays={"x": (*lead, per, M, rows), "w": (*lead, rows, N),
                 "scales": (*lead, G, 1, N), "out": (*lead, M, N)},
         scratch={"acc": ((bm, bn), 4)},
+        temps={"planes": ((per + 1 + (G > 1), rs, bn), 4),
+               "dots": ((bkp // rs, bm, bn), 4)},
         meta={"bk": bkp * per, "bkp": bkp, "nk": nk, "ng": ng, "bm": bm,
-              "bn": bn, "per": per}))
+              "bn": bn, "per": per, "rs": rs, "x_resident": True})
+    if nk > 1 and sp.vmem_bytes > VMEM_BUDGET_BYTES:
+        sp = dataclasses.replace(
+            sp, blocks={**sp.blocks, "x": ((*one, per, bm, bkp), x_bytes)},
+            meta={**sp.meta, "x_resident": False})
+    return check_block_tiling(sp)
 
 
-def qmm_row_tile(M: int, K: int, G: int, *, bits: int, bn: int,
-                 x_bytes: int = 4) -> int:
-    """Rows per ``qmatmul``/``qmatmul_grouped`` program: 128 when M tiles
-    by it, else one 8-row sublane tile (the caller zero-pads M), halved
-    while the launch overruns the VMEM budget — a k-step spans all K/per
-    packed rows when they have no 128-lane divisor (d_ff 10944)."""
-    bm = 128 if M % 128 == 0 else 8
-    wp = (K // _bits_per("qmatmul", bits), bn)
-    while bm > 8 and _describe_qmm(
-            "qmatmul", (bm, K), wp, (G, bn), bits=bits, bm=bm, bn=bn,
-            x_bytes=x_bytes).vmem_bytes > VMEM_BUDGET_BYTES:
-        bm //= 2
-    return bm
+def _row_blocks(M: int) -> list[int]:
+    """Row-block sizes to try, largest first: all M rows up to
+    QMM_ONE_BLOCK_ROWS (then its 8-row divisors); above, 128 when M
+    tiles by it, else one 8-row sublane tile (the caller zero-pads M),
+    halving down to 8."""
+    if M <= QMM_ONE_BLOCK_ROWS:
+        return [M] + [d for d in range(M - 8, 7, -8) if M % d == 0]
+    return [128, 64, 32, 16, 8] if M % 128 == 0 else [8]
+
+
+@functools.lru_cache(maxsize=1024)
+def qmm_tiles(M: int, K: int, N: int, G: int, *, bits: int,
+              x_bytes: int = 4) -> tuple[int, int, int]:
+    """(bm, bkp, bn) of a qmm launch: M activation rows against packed
+    (K*bits/8, N) codes with G scale groups — the one tile choice that
+    ``qgemv``, ``qmatmul``, ``qmatmul_grouped`` and the kernel audit
+    share.
+
+    The row block is the largest of :func:`_row_blocks` under which
+    some weight tile fits the VMEM budget. The weight tile comes from
+    the aligned divisors of K/per and N: the largest packed step of at
+    most QMM_STEP_BYTES (where every step is larger, the smallest),
+    preferring a bn narrow enough that an aligned row slice holds at
+    most QMM_SLICE_BYTES, then the wider bn among equals. A launch
+    nothing fits gets the smallest row block's best tile, and its spec
+    fails ``check_budget``."""
+    per = _bits_per("qmm", bits)
+    rows, align = _k_layout("qmm", K, G, per)
+
+    def rank(t):
+        step = t[0] * t[1]
+        return (step <= QMM_STEP_BYTES, t[1] * align <= QMM_SLICE_BYTES,
+                step if step <= QMM_STEP_BYTES else -step, t[1])
+
+    tiles = sorted(((bkp, bn) for bkp in _aligned_divisors(rows, align)
+                    for bn in _aligned_divisors(N, 128)), key=rank,
+                   reverse=True)
+    for bm in _row_blocks(M):
+        for bkp, bn in tiles:
+            if _describe_qmm("qmm", (bm, K), (rows, N), (G, N), bits=bits,
+                             bm=bm, bkp=bkp, bn=bn, x_bytes=x_bytes
+                             ).vmem_bytes <= VMEM_BUDGET_BYTES:
+                return bm, bkp, bn
+    return bm, *tiles[0]
 
 
 def describe_qmatmul(x_shape, wp_shape, scales_shape, *, bits: int,
-                     bm: int, bn: int, x_bytes: int = 4) -> KernelSpec:
+                     bm: int, bkp: int, bn: int,
+                     x_bytes: int = 4) -> KernelSpec:
     """Validate + describe a ``qmatmul`` (prefill GEMM) launch.
 
     x (M, K) @ dequant(wp (K*bits/8, N), scales (K/G, N)) -> (M, N),
     grid (M/bm, N/bn, nk).
     """
     return _describe_qmm("qmatmul", x_shape, wp_shape, scales_shape,
-                         bits=bits, bm=bm, bn=bn, x_bytes=x_bytes)
+                         bits=bits, bm=bm, bkp=bkp, bn=bn, x_bytes=x_bytes)
 
 
 def describe_qgemv(x_shape, wp_shape, scales_shape, *, bits: int,
-                   bn: int, x_bytes: int = 4) -> KernelSpec:
+                   bkp: int, bn: int, x_bytes: int = 4) -> KernelSpec:
     """Validate + describe a ``qgemv`` (decode GEMV) launch.
 
     The whole M extent (decode batch rows) is one skinny block; grid
     (N/bn, nk) with the (M, bn) accumulator VMEM-resident.
     """
     sp = _describe_qmm("qgemv", x_shape, wp_shape, scales_shape, bits=bits,
-                       bm=x_shape[0], bn=bn, x_bytes=x_bytes)
+                       bm=x_shape[0], bkp=bkp, bn=bn, x_bytes=x_bytes)
     return dataclasses.replace(sp, grid=sp.grid[1:])
 
 
 def describe_qmatmul_grouped(x_shape, wp_shape, scales_shape, *, bits: int,
-                             bm: int, bn: int, x_bytes: int = 4) -> KernelSpec:
+                             bm: int, bkp: int, bn: int,
+                             x_bytes: int = 4) -> KernelSpec:
     """Validate + describe a ``qmatmul_grouped`` (stacked experts) launch.
 
     x (E, M, K) @ dequant((E, K*bits/8, N)) -> (E, M, N), expert-major
     grid (E, M/bm, N/bn, nk).
     """
     return _describe_qmm("qmatmul_grouped", x_shape, wp_shape, scales_shape,
-                         bits=bits, bm=bm, bn=bn, x_bytes=x_bytes)
+                         bits=bits, bm=bm, bkp=bkp, bn=bn, x_bytes=x_bytes)
 
 
 def kv_stream_tile(S: int) -> int:

@@ -29,7 +29,7 @@ from repro.kernels.spec import (VMEM_BUDGET_BYTES, BlockTilingError,
                                 describe_fakequant, describe_kv_decode,
                                 describe_qgemv, describe_qmatmul,
                                 describe_qmatmul_grouped, fakequant_tiles,
-                                qmm_row_tile)
+                                qmm_tiles)
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -176,19 +176,19 @@ def test_seeded_mistiled_blockspec_fires():
     # bm does not divide M
     with pytest.raises(KernelSpecError, match="M=100 is not a multiple"):
         describe_qmatmul((100, 64), (32, 128), (1, 128), bits=4, bm=128,
-                         bn=128)
+                         bkp=32, bn=128)
     # packed rows inconsistent with K/bits
     with pytest.raises(KernelSpecError, match="values/byte"):
-        describe_qgemv((4, 64), (40, 128), (1, 128), bits=4, bn=128)
+        describe_qgemv((4, 64), (40, 128), (1, 128), bits=4, bkp=40, bn=128)
     # expert axes disagree
     with pytest.raises(KernelSpecError, match="expert axes"):
         describe_qmatmul_grouped((4, 8, 64), (3, 32, 128), (3, 1, 128),
-                                 bits=4, bm=8, bn=128)
+                                 bits=4, bm=8, bkp=32, bn=128)
 
 
 def test_seeded_vmem_blowup_fires():
     sp = describe_qmatmul((4096, 512), (256, 8192), (1, 8192), bits=4,
-                          bm=4096, bn=8192)
+                          bm=4096, bkp=256, bn=8192)
     assert sp.vmem_bytes > VMEM_BUDGET_BYTES
     with pytest.raises(KernelSpecError, match="exceeds the declared budget"):
         sp.check_budget()
@@ -253,14 +253,43 @@ def test_aligned_tile_picks_lane_aligned_divisors():
 def test_row_and_fakequant_tiles_fit_vmem_for_untiled_k():
     """d_ff 10944 has no 128-lane divisor, so a k-step or a fakequant
     tile spans it whole; the pickers shrink the other tile to fit."""
-    bm = qmm_row_tile(128, 10944, 1, bits=4, bn=128)
-    assert 8 <= bm < 128
+    bm, bkp, bn = qmm_tiles(128, 10944, 128, 1, bits=4)
+    assert bkp == 5472 and 8 <= bm < 128 and bn == 128
     describe_qmatmul((128, 10944), (5472, 128), (1, 128), bits=4, bm=bm,
-                     bn=128).check_budget()
+                     bkp=bkp, bn=bn).check_budget()
     bk, bn = fakequant_tiles(2048, 10944)
     assert bn == 10944 and bk % 8 == 0
     describe_fakequant((2048, 10944), (1, 10944), bk=bk,
                        bn=bn).check_budget()
+
+
+# InternLM2-20B's packed linears: wq, wk, wv, wo, w_gate, w_up, w_down
+# (W4, per column) and the untied 8-bit head.
+INTERNLM2_20B = [(6144, 6144, 4), (6144, 1024, 4), (6144, 1024, 4),
+                 (6144, 6144, 4), (6144, 16384, 4), (6144, 16384, 4),
+                 (16384, 6144, 4)]
+INTERNLM2_20B_HEAD = (6144, 92544, 8)
+
+
+@pytest.mark.parametrize("M", [32, 8])
+def test_qmm_tiles_stream_weights_once_at_internlm2_20b(M):
+    """A 32-slot decode step (or a 32-row prefill chunk) is one row block,
+    so each packed weight tile streams once per call, in steps large
+    enough that 20 layers and the head take under 10,000 grid steps
+    (bm = 8, bn = 128 and 512-row k-steps took 272,784); every launch
+    fits the VMEM budget with its unpacked field planes counted."""
+    steps = 0
+    for (K, N, bits), layers in [*[(s, 20) for s in INTERNLM2_20B],
+                                 (INTERNLM2_20B_HEAD, 1)]:
+        bm, bkp, bn = qmm_tiles(M, K, N, 1, bits=bits)
+        assert bm == M
+        sp = describe_qmatmul((M, K), (K * bits // 8, N), (1, N), bits=bits,
+                              bm=bm, bkp=bkp, bn=bn)
+        sp.check_budget()
+        assert sp.grid[0] == 1 and sp.meta["x_resident"]
+        assert "planes" in sp.temps and bkp * bn >= 256 * 1024
+        steps += layers * sp.num_programs
+    assert steps < 10_000
 
 
 # ---------------------------------------------------------------------------

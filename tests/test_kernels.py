@@ -96,6 +96,61 @@ def test_qmm_ragged_n_pads_lanes(rng, bits, M, N):
                                atol=1e-3, rtol=1e-3)
 
 
+def _packed_linear(rng, K, N, bits, group=None):
+    w = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    cfg = QConfig(bits=bits, channel_axis=-1, group_size=group)
+    st = init_qstate(w, cfg)
+    return pack_weights(quantize_int(w, st, cfg), st.scale.reshape(-1, N),
+                        bits)
+
+
+@pytest.mark.parametrize("group", [None, 64], ids=["per_channel", "g64"])
+@pytest.mark.parametrize("bits", [2, 4, 8])
+@pytest.mark.parametrize("M", [8, 32, 256])
+@pytest.mark.parametrize("K,N", [(1024, 640), (10944, 128)],
+                         ids=["n640", "dff10944"])
+def test_qmm_picked_tiles_vs_ref(rng, K, N, M, bits, group):
+    """The tiles ``spec.qmm_tiles`` picks, through the tier dispatch (M = 8
+    runs ``qgemv``, 32 and 256 one ``qmatmul`` row block): N = 5 x 128
+    has no 512 divisor, and d_ff 10944's packed rows have no 128 divisor,
+    so its k-step spans them all."""
+    qw = _packed_linear(rng, K, N, bits, group)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    out = qmm(x, qw, backend="pallas")
+    ref = (qgemv_ref if M <= 8 else qmatmul_ref)(x, qw.packed, qw.scales,
+                                                  bits)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("group", [None, 64], ids=["per_channel", "g64"])
+@pytest.mark.parametrize("resident", [True, False], ids=["x_resident",
+                                                          "x_walks_k"])
+def test_qmatmul_k_steps_in_row_slices(rng, monkeypatch, resident, group):
+    """Two k-steps of 512 packed rows, each unpacked 256 rows at a time in
+    the kernel's fori_loop, with the x block holding every packed row or
+    (under a budget too small for that) walking k with the weights."""
+    from repro.kernels import spec
+
+    M, K, N, bits = (24 if resident else 40), 2048, 384, 4
+    qw = _packed_linear(rng, K, N, bits, group)
+    G = qw.scales.shape[0]
+    describe = lambda: spec.describe_qmatmul(
+        (M, K), qw.packed.shape, (G, N), bits=bits, bm=M, bkp=512, bn=N)
+    if not resident:
+        monkeypatch.setattr(spec, "VMEM_BUDGET_BYTES",
+                            describe().vmem_bytes - 1)
+    sp = describe()
+    assert (sp.meta["nk"], 512 // sp.meta["rs"]) == (2, 2)
+    assert sp.meta["x_resident"] == resident
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    out = qmatmul(x, qw.packed, qw.scales, bits=bits, bm=M, bkp=512, bn=N,
+                  interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(qmatmul_ref(x, qw.packed, qw.scales, bits)),
+        atol=1e-3, rtol=1e-3)
+
+
 # ---------------------------------------------------------------------------
 # decode tier: qgemv
 # ---------------------------------------------------------------------------

@@ -21,11 +21,15 @@ import pytest
 
 from repro.kernels.kvattn.kernel import kv_decode
 from repro.kernels.qmatmul.kernel import qgemv, qmatmul, qmatmul_grouped
-from repro.kernels.spec import kv_stream_tile, qmm_row_tile
+from repro.kernels.spec import kv_stream_tile
 
 # brecq_lm_100m's (K, N) pairs: wq/wk/wv/wo, w_up/w_gate, w_down and the
 # tied 8192-way head.
 QMM_SHAPES = [(768, 768), (768, 2048), (2048, 768), (768, 8192)]
+# InternLM2-20B's (K, N, bits): wq/wo, wk/wv, w_gate/w_up, w_down at W4
+# and the untied 8-bit 92544-way head (723 x 128 columns).
+INTERNLM2_20B_SHAPES = [(6144, 6144, 4), (6144, 1024, 4), (6144, 16384, 4),
+                        (16384, 6144, 4), (6144, 92544, 8)]
 DECODE_M = 8      # engine decode batch: num_slots rows
 PREFILL_M = 32    # one prefill chunk of one stream
 EXPERTS = 4
@@ -72,19 +76,35 @@ def test_qmm_tier_compiles_for_v5e(one_chip, tier, bits, grouped):
         w = ((K // per, N), jnp.int8)
         s = ((G, N), jnp.float32)
         if tier == "qgemv":
-            fn = lambda x, wp, sc: qgemv(x, wp, sc, bits=bits, bn=128)
+            fn = lambda x, wp, sc: qgemv(x, wp, sc, bits=bits)
             _compile(fn, ((DECODE_M, K), jnp.float32), w, s, sharding=one_chip)
         elif tier == "qmatmul":
-            bm = qmm_row_tile(PREFILL_M, K, G, bits=bits, bn=128)
-            fn = lambda x, wp, sc: qmatmul(x, wp, sc, bits=bits, bm=bm, bn=128)
+            fn = lambda x, wp, sc: qmatmul(x, wp, sc, bits=bits)
             _compile(fn, ((PREFILL_M, K), jnp.float32), w, s, sharding=one_chip)
         else:
-            bm = qmm_row_tile(PREFILL_M, K, G, bits=bits, bn=128)
-            fn = lambda x, wp, sc: qmatmul_grouped(x, wp, sc, bits=bits,
-                                                   bm=bm, bn=128)
+            fn = lambda x, wp, sc: qmatmul_grouped(x, wp, sc, bits=bits)
             lead = lambda sh: ((EXPERTS, *sh[0]), sh[1])
             _compile(fn, lead(((PREFILL_M, K), jnp.float32)), lead(w),
                      lead(s), sharding=one_chip)
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["per_channel", "g64"])
+@pytest.mark.parametrize("M", [32, 8, 256])
+@pytest.mark.parametrize("K,N,bits", INTERNLM2_20B_SHAPES,
+                         ids=lambda v: str(v))
+def test_qmm_internlm2_20b_compiles_for_v5e(one_chip, K, N, bits, M, grouped):
+    """The serving kernels at InternLM2-20B's widths with the tiles
+    ``spec.qmm_tiles`` picks: M = 32 (32 decode slots, or a 32-row
+    prefill chunk) runs ``qmatmul`` as one row block with the x block
+    resident, M = 8 runs ``qgemv``, and M = 256, the largest single row
+    block, holds the unrolled slices' partial sums inside the compiler's
+    VMEM limit."""
+    per = 8 // bits
+    G = K // 64 if grouped else 1
+    kern = qgemv if M <= 8 else qmatmul
+    fn = lambda x, wp, sc: kern(x, wp, sc, bits=bits)
+    _compile(fn, ((M, K), jnp.float32), ((K // per, N), jnp.int8),
+             ((G, N), jnp.float32), sharding=one_chip)
 
 
 @pytest.mark.parametrize("S", [288, 512])
