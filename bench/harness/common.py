@@ -58,15 +58,32 @@ def cell_metrics(bench: dict, cell: str, trace: bool) -> list[dict]:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
+def _load_module(path: Path, name: str):
+    """The Python file ``path`` as a module registered under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str):
     """The per-layer metric reader ``bench/metrics/<name>.py``: a module
     with ``read(run) -> float | None``."""
-    path = BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(BENCH_DIR / "metrics" / f"{name}.py",
+                        f"bench_metric_{name.replace('.', '_')}").read
+
+
+def load_arch(config: dict):
+    """The architecture module ``bench/archs/<arch>.py`` that a
+    configuration file names under ``"arch"``: its sizes, weights,
+    reference layer and work counts (see ``bench/archs/dense_gqa.py``)."""
+    have = sorted(p.stem for p in (BENCH_DIR / "archs").glob("*.py"))
+    name = config.get("arch")
+    if name not in have:
+        raise BenchError(f"configuration {config.get('name')!r} names "
+                         f"architecture {name!r}; bench/archs has {have}")
+    return _load_module(BENCH_DIR / "archs" / f"{name}.py", f"bench_arch_{name}")
 
 
 def load_peaks(device_kind: str, path: Path = PEAKS_FILE) -> dict:

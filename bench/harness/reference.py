@@ -1,5 +1,7 @@
-"""Plain reference of the served model: a dense GQA decoder with RoPE,
-RMSNorm and a SwiGLU feed-forward, in straightforward ``jax.numpy``.
+"""The plain reference of a served model, in straightforward
+``jax.numpy``: what every architecture shares. Each architecture module
+(``bench/archs/<arch>.py``) brings its own layer (``block``) and its
+reference weights; this module walks them.
 
 It imports nothing of the program. It makes its weights again from the
 seed (``weights.py``) and applies the deployment's stated quantization
@@ -26,8 +28,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from . import weights as W
 
 F32 = jnp.float32
 
@@ -72,68 +72,29 @@ def rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def block(dims: W.Dims, w: dict, x, operands=None, kv8: bool = True):
-    """One decoder layer over one request's (S, d) hidden states."""
-    r = _rounder(operands)
-
-    def mm(a, b):
-        return jnp.matmul(r(a), r(b), precision=HIGHEST)
-
-    def es(spec, a, b):
-        return jnp.einsum(spec, r(a), r(b), precision=HIGHEST)
-
-    S = x.shape[0]
-    H, K, hd = dims.n_heads, dims.n_kv_heads, dims.head_dim
-    h = rmsnorm(x, w["norm1"], dims.norm_eps)
-    q = rope(mm(h, w["wq"]).reshape(S, H, hd), dims.rope_theta)
-    k = rope(mm(h, w["wk"]).reshape(S, K, hd), dims.rope_theta)
-    v = mm(h, w["wv"]).reshape(S, K, hd)
-    if kv8:
-        k, v = kv_int8(k), kv_int8(v)
-    qg = q.reshape(S, K, H // K, hd)
-    s = es("qkgd,pkd->kgqp", qg, k) / jnp.sqrt(jnp.asarray(hd, F32))
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    a = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
-    o = es("kgqp,pkd->qkgd", a, v).reshape(S, H * hd)
-    x = x + mm(o, w["wo"])
-    h = rmsnorm(x, w["norm2"], dims.norm_eps)
-    return x + mm(jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"]),
-                  w["w_down"])
-
-
-def served_weights(dims: W.Dims, w_bits: int):
-    """(outer, layer) weight makers of the served model: the seed's
-    weights under the deployment's round-to-nearest. Both take the seed
-    halves as traced arguments, so one compiled program serves every
-    seed."""
-
-    def outer(lo, hi):
-        o = W.outer(dims, lo, hi)
-        emb = rtn(o["embed"], 8)
-        return emb, o["final_norm"], (emb.T if dims.tie else rtn(o["head"], 8))
-
-    def layer(lo, hi, i):
-        return {n: (a if n.startswith("norm") else rtn(a, w_bits))
-                for n, a in W.layer(dims, lo, hi, i).items()}
-
-    return outer, layer
-
-
 class Reference:
-    """The model over given weights: ``outer(*args) -> (embedding (V, d),
-    final norm gain, head (d, V))`` and ``layer(*args, i) -> leaves``,
-    both traceable in ``args`` and the layer index (unless
-    ``traced=False``: then they are called as they are and return
-    arrays). Call :meth:`logits`."""
+    """The model of architecture module ``arch`` under the deployment's
+    quantization at ``w_bits``, its weights made again from the seed
+    halves ``args`` (``arch.reference_weights``). ``arch.block`` applies
+    layer ``i``. Call :meth:`logits`.
 
-    def __init__(self, dims: W.Dims, outer, layer, args=(), operands=None,
-                 kv8: bool = True, traced: bool = True):
-        self.dims, self.args = dims, args
+    Layers are grouped by ``arch.layer_kind(dims, i)`` (every layer is of
+    kind 0 where the module has none): a module's layer maker and block
+    may give each kind its own leaves and code, and each kind compiles
+    once, with the layer index traced."""
+
+    def __init__(self, arch, dims, w_bits: int, args=(), operands=None,
+                 kv8: bool = True):
+        self.arch, self.dims, self.args = arch, dims, args
+        outer, layer = arch.reference_weights(dims, w_bits)
+        kind = getattr(arch, "layer_kind", lambda d, i: 0)
+        self.kinds = [kind(dims, i) for i in range(arch.reference_layers(dims))]
         r = _rounder(operands)
-        self._outer = jax.jit(outer) if traced else outer
-        self._layer = jax.jit(layer) if traced else layer
-        self._blocks = jax.jit(lambda w, xs: jax.lax.map(
-            lambda x: block(dims, w, x, operands, kv8), xs))
+        self._outer = jax.jit(outer)
+        self._layer = jax.jit(layer, static_argnames="kind")
+        self._blocks = jax.jit(lambda i, w, xs, kind: jax.lax.map(
+            lambda x: arch.block(dims, i, w, x, operands, kv8, kind=kind), xs),
+            static_argnames="kind")
         self._embed = jax.jit(lambda e, t: e[t])
 
         def head(xs, idx, g, hw):
@@ -150,8 +111,8 @@ class Reference:
         emb, g, hw = self._outer(*self.args)
         xs = self._embed(emb, jnp.asarray(tokens))
         del emb
-        for i in range(self.dims.n_layers):
-            xs = self._blocks(self._layer(*self.args, i), xs)
+        for i, k in enumerate(self.kinds):
+            xs = self._blocks(i, self._layer(*self.args, i, kind=k), xs, kind=k)
         return self._head(xs, jnp.asarray(positions), g, hw)
 
 

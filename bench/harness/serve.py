@@ -1,9 +1,12 @@
 """Open-loop serving of one configuration through ``repro.serve_engine``.
 
-Set-up makes the served weights on the device from the seed (one jitted
-program: the float32 tree and the program's own packing,
-``repro.deploy.quantize_tree``), builds the engine, compiles its two
-programs and warms every host-side shape the window will use. The
+The configuration's architecture module (``bench/archs/<arch>.py``,
+``common.load_arch``) gives the sizes, the program's ``ArchConfig`` and
+the weights. Set-up makes the served weights on the device from the
+seed (the module's ``served_maker``: the float32 tree under the
+program's own packing, ``repro.deploy.quantize_tree``), builds the
+engine, compiles its two programs and warms every host-side shape the
+window will use. The
 window submits each request at its scheduled arrival and ticks the
 engine whenever it has work; every request due in the window is then
 followed to its end. A request's times are those at which the host saw
@@ -21,22 +24,9 @@ import numpy as np
 
 from . import common as C
 from . import traffic as T
-from . import weights as W
-from .reference import Reference, served_gaps, served_weights
+from .reference import Reference, served_gaps
 
 DRAIN_LIMIT_S = 60.0
-
-
-def arch_config(config: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig
-
-    d = W.Dims.from_config(config)
-    return ArchConfig(
-        name=config["name"], family="dense", n_layers=d.n_layers,
-        d_model=d.d_model, n_heads=d.n_heads, n_kv_heads=d.n_kv_heads,
-        d_ff=d.d_ff, vocab=d.vocab, head_dim=d.head_dim,
-        rope_theta=d.rope_theta, tie_embeddings=d.tie)
 
 
 def engine_config(config: dict):
@@ -75,6 +65,21 @@ def end_to_end(o: dict, seconds: float) -> dict:
             "out_tok_s": o["tokens_in_window"] / seconds}
 
 
+def decode_note(eng) -> list[int]:
+    """The live streams' lengths at a decode call."""
+    return [len(r.prompt) + len(r.generated) for r in eng.slot_req
+            if r is not None and r.state == "decode"]
+
+
+def chunk_note(eng) -> tuple[int, int]:
+    """(rows, offset) of the prompt rows a prefill-chunk call runs."""
+    ns = eng.cfg.num_slots
+    req = eng.slot_req[(eng._pf_ptr - 1) % ns]
+    src = req.prefill_src if req.prefill_src is not None else req.prompt
+    rows = min(eng.cfg.prefill_chunk, len(src) - req.prefill_off)
+    return rows, req.prefill_off
+
+
 class _Recorder:
     """Stands in for one of the engine's compiled programs and notes, per
     call, the work the call needs (from the engine's host-side state)."""
@@ -91,7 +96,8 @@ class _Recorder:
 class ServeRun:
     def __init__(self, config: dict, mix: dict, seed: int, seconds: float):
         self.config, self.mix, self.seed, self.seconds = config, mix, seed, seconds
-        self.dims = W.Dims.from_config(config)
+        self.arch = C.load_arch(config)
+        self.dims = self.arch.dims(config)
         self.q = config["quant"]
         self.compiles = 0
 
@@ -105,8 +111,8 @@ class ServeRun:
         from repro.serve_engine import ServeEngine
 
         d, q = self.dims, self.q
-        model = LM(arch_config(self.config))
-        make = W.served_maker(
+        model = LM(self.arch.arch_config(self.config, d))
+        make = self.arch.served_maker(
             d, lambda tree: quantize_tree(tree, q["w_bits"], q["w_group"]))
         params = make(*C.split_seed(self.seed))
         jax.block_until_ready(params)
@@ -124,21 +130,14 @@ class ServeRun:
             self.compiles += 1
 
     def _wrap_programs(self) -> None:
+        """Note each program call's work: with the architecture module's
+        ``decode_note`` / ``chunk_note`` where it has them, else this
+        module's."""
         eng = self.engine
-
-        def decode_note():
-            return [len(r.prompt) + len(r.generated) for r in eng.slot_req
-                    if r is not None and r.state == "decode"]
-
-        def chunk_note():
-            ns = eng.cfg.num_slots
-            req = eng.slot_req[(eng._pf_ptr - 1) % ns]
-            src = req.prefill_src if req.prefill_src is not None else req.prompt
-            rows = min(eng.cfg.prefill_chunk, len(src) - req.prefill_off)
-            return rows, req.prefill_off
-
-        eng._decode_c = self.decode_rec = _Recorder(eng._decode_c, decode_note)
-        eng._chunk_c = self.chunk_rec = _Recorder(eng._chunk_c, chunk_note)
+        dn = getattr(self.arch, "decode_note", decode_note)
+        cn = getattr(self.arch, "chunk_note", chunk_note)
+        eng._decode_c = self.decode_rec = _Recorder(eng._decode_c, lambda: dn(eng))
+        eng._chunk_c = self.chunk_rec = _Recorder(eng._chunk_c, lambda: cn(eng))
 
     def _warm_up(self) -> None:
         """Requests whose prompts end at every offset inside a prefill
@@ -311,11 +310,10 @@ class ServeRun:
             pos[b, :len(s)] = len(p) - 1 + np.arange(len(s))
             served[b, :len(s)] = s
             valid[b, :len(s)] = True
-        outer, layer = served_weights(d, self.q["w_bits"])
-        halves = C.split_seed(self.seed)
-        ref = Reference(d, outer, layer, halves).logits(toks, pos)
+        args = (self.arch, d, self.q["w_bits"], C.split_seed(self.seed))
+        ref = Reference(*args).logits(toks, pos)
         if operands is not None:
-            low = Reference(d, outer, layer, halves, operands).logits(toks, pos)
+            low = Reference(*args, operands).logits(toks, pos)
             served = np.asarray(jnp.argmax(low, axis=-1), np.int32)
         g = np.asarray(served_gaps(ref, jnp.asarray(served), jnp.asarray(valid)))
         return {"gap_max": float(g.max()),

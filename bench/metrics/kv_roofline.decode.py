@@ -9,5 +9,6 @@ def read(ctx):
     t = ctx.trace.kernel_time("decode_fn", ("kv_decode",))
     if not calls or not t:
         return None
-    need = sum(F.kv_min_time(ctx.dims, lens, ctx.peaks) for lens in calls)
-    return 100.0 * need / t
+    need = F.total(ctx.arch.kv_min_time(ctx.dims, note, ctx.peaks)
+                   for note in calls)
+    return None if need is None else 100.0 * need / t

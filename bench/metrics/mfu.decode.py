@@ -8,5 +8,7 @@ def read(ctx):
     runs = ctx.trace.module_runs("decode_fn")
     if not calls or not runs:
         return None
-    work = sum(F.model_flops_decode(ctx.dims, lens) for lens in calls)
+    work = F.total(ctx.arch.model_flops_decode(ctx.dims, note) for note in calls)
+    if work is None:
+        return None
     return 100.0 * work / sum(r.dur for r in runs) / ctx.peaks["bf16_flops_per_s"]

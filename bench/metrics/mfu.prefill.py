@@ -9,5 +9,7 @@ def read(ctx):
     runs = ctx.trace.module_runs("chunk_fn")
     if not calls or not runs:
         return None
-    work = sum(F.model_flops_prefill(ctx.dims, rows, off) for rows, off in calls)
+    work = F.total(ctx.arch.chunk_flops(ctx.dims, note) for note in calls)
+    if work is None:
+        return None
     return 100.0 * work / sum(r.dur for r in runs) / ctx.peaks["bf16_flops_per_s"]

@@ -4,7 +4,7 @@ untied head) against the live decode rows of each call, over the time
 the qmm kernels (whichever tier) took there."""
 from harness import flops as F
 
-KERNELS = ("qgemv", "qmatmul")
+KERNELS = ("qgemv", "qmatmul", "qmatmul_grouped")
 
 
 def read(ctx):
@@ -13,5 +13,6 @@ def read(ctx):
     if not calls or not t:
         return None
     bits = ctx.run.q["w_bits"]
-    need = sum(F.qmm_min_time(ctx.dims, len(lens), bits, ctx.peaks) for lens in calls)
-    return 100.0 * need / t
+    need = F.total(ctx.arch.decode_qmm_s(ctx.dims, note, bits, ctx.peaks)
+                   for note in calls)
+    return None if need is None else 100.0 * need / t

@@ -3,7 +3,7 @@ program: the least time for every packed weight against the chunk's real
 prompt rows, over the time the qmm kernels took there."""
 from harness import flops as F
 
-KERNELS = ("qgemv", "qmatmul")
+KERNELS = ("qgemv", "qmatmul", "qmatmul_grouped")
 
 
 def read(ctx):
@@ -12,5 +12,6 @@ def read(ctx):
     if not calls or not t:
         return None
     bits = ctx.run.q["w_bits"]
-    need = sum(F.qmm_min_time(ctx.dims, rows, bits, ctx.peaks) for rows, _ in calls)
-    return 100.0 * need / t
+    need = F.total(ctx.arch.chunk_qmm_s(ctx.dims, note, bits, ctx.peaks)
+                   for note in calls)
+    return None if need is None else 100.0 * need / t

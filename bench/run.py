@@ -11,8 +11,11 @@ With ``--trace 0`` the result carries the cell's end-to-end metrics;
 with ``--trace 1`` a profiler trace of part of the window is reduced to
 its per-layer metrics (one reader each, ``bench/metrics/<metric>.py``).
 
-Every run then checks what the timed path produced against the plain
-reference (``bench/harness/reference.py``), prints each compared number
+The configuration names its architecture (``"arch"``), whose module
+``bench/archs/<arch>.py`` gives the sizes, the weights, the reference
+layer and the work counts. Every run then checks what the timed path
+produced against the plain reference (``bench/harness/reference.py``
+walking that module's layers), prints each compared number
 beside its limit as the last lines of stderr, and prints the result as
 the last line of stdout. It exits non-zero with no result when JAX finds
 no TPU, fewer chips than the cell asks for, a chip missing from
@@ -46,7 +49,7 @@ class Ctx:
 
     def __init__(self, run, trace, peaks):
         self.run, self.trace, self.peaks = run, trace, peaks
-        self.dims = run.dims
+        self.arch, self.dims = run.arch, run.dims
 
 
 def main(argv=None) -> int:
@@ -72,6 +75,11 @@ def main(argv=None) -> int:
         devices = jax.devices()
         device = C.check_devices(devices, cell["chips"])
         peaks = C.load_peaks(device["kind"])
+        if args.rate is not None:
+            mix = dict(mix, rate_rps=args.rate)
+        from harness.serve import ServeRun
+
+        run = ServeRun(config, mix, args.seed, args.seconds)
     except C.BenchError as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
@@ -81,11 +89,6 @@ def main(argv=None) -> int:
     print(f"[bench] {args.workload} seed {args.seed} on {device['kind']}; "
           f"compile cache {cache}", file=sys.stderr)
 
-    if args.rate is not None:
-        mix = dict(mix, rate_rps=args.rate)
-    from harness.serve import ServeRun
-
-    run = ServeRun(config, mix, args.seed, args.seconds)
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if args.trace else None
     try:
         run.setup(record=bool(args.trace))

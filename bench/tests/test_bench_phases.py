@@ -198,15 +198,15 @@ def _recorded_ctx(rec):
     import types
 
     from harness import common as C
-    from harness.weights import Dims
 
     bench = C.load_benchmark()
     _, config, _ = C.find_cell(bench, bench["workloads"][0]["name"])
+    arch = C.load_arch(config)
     run = types.SimpleNamespace(decode_calls=[[40] * 20] * 3,
-                                chunk_calls=[(20, 0)] * 3,
-                                q=config["quant"], dims=Dims.from_config(config))
-    return types.SimpleNamespace(run=run, trace=Trace(rec), dims=run.dims,
-                                 peaks=C.load_peaks("TPU v5 lite"))
+                                chunk_calls=[(20, 0)] * 3, q=config["quant"],
+                                arch=arch, dims=arch.dims(config))
+    return types.SimpleNamespace(run=run, trace=Trace(rec), arch=arch,
+                                 dims=run.dims, peaks=C.load_peaks("TPU v5 lite"))
 
 
 def test_existing_readers_unchanged_by_engine_spans():

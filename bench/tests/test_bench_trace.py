@@ -90,3 +90,28 @@ def test_recorded_trace():
     b = tr.breakdown()
     assert len(b["device_ops"]) == 10 and len(b["idle_gaps"]) <= 10
     assert all(t > 0 for _, t in b["device_ops"])
+
+
+@pytest.mark.parametrize("reader,prog", [("qmm_roofline.decode", "decode_fn"),
+                                         ("qmm_roofline.prefill", "chunk_fn")])
+def test_qmm_readers_count_the_grouped_tier(reader, prog):
+    """A stacked-expert matmul runs ``qmatmul_grouped`` (its HLO name in a
+    compiled v5e program); its time counts beside the other tiers'."""
+    from types import SimpleNamespace
+
+    from harness import common as C
+
+    start = 10 if prog == "decode_fn" else 60
+    ops = [["%qmatmul.3", start + 5, 10, {"kernel": 1}],
+           ["%qmatmul_grouped.5", start + 20, 6, {"kernel": 1}]]
+    mods = [["jit_decode_fn", 10, 40], ["jit_chunk_fn", 60, 30]]
+    tr = Trace(_rec(ops, mods, [["bench.window", 0, 100]]))
+    arch = SimpleNamespace(decode_qmm_s=lambda *a: 8e-9,
+                           chunk_qmm_s=lambda *a: 8e-9)
+    run = SimpleNamespace(decode_calls=[[5]], chunk_calls=[(5, 0)],
+                          q={"w_bits": 4})
+    ctx = SimpleNamespace(run=run, trace=tr, arch=arch, dims=None, peaks={})
+    read = C.load_reader(reader)
+    assert "qmatmul_grouped" in read.__globals__["KERNELS"]
+    # 8 ns of need over 10 + 6 ns of qmm kernels
+    assert read(ctx) == pytest.approx(50.0)
